@@ -19,17 +19,12 @@ import numpy as np
 from repro.obs.timing import timeit_us as _time
 
 
-def run(quiet: bool = False, sharded: bool = False,
-        fleet: bool = False) -> List[Dict]:
-    """``sharded=True`` (CLI: ``--sharded``) adds the mesh-sharded /
-    donated single-run rows — they spawn a multi-device
-    ``scripts/bench_el.py`` subprocess (minutes, needs forced host
-    devices), so they are opt-in and the default run keeps the quick
-    in-process contract existing callers (``benchmarks.run``) rely on;
-    the committed ``BENCH_el.json`` is the canonical record of those
-    tiers.  ``fleet=True`` (CLI: ``--fleet``) likewise adds the
-    multi-tenant serving row via a ``scripts/bench_fleet.py``
-    subprocess; ``BENCH_fleet.json`` is its canonical record."""
+def run(quiet: bool = False) -> List[Dict]:
+    """All rows run in this process.  The mesh-sharded / donated
+    single-run tiers and the fleet serving row are
+    ``scripts/bench_el.py`` and ``scripts/bench_fleet.py``: run those
+    directly (a child started from here could not get a device this
+    process already holds)."""
     rows = []
 
     # bandit decision latency (cloud control plane)
@@ -193,20 +188,6 @@ def run(quiet: bool = False, sharded: bool = False,
                 f"speedup={seq_host_us / max(sweep_us, 1e-9):.1f}"
                 "x_vs_seq_host"))
 
-    # mesh-sharded + donated single-run data plane vs the replicated
-    # in-graph program (scripts/bench_el.py in a subprocess — the
-    # sharded rows need forced host devices, which must be set before
-    # jax initializes, so they cannot run in this process)
-    if sharded:
-        rows.extend(_sharded_rows())
-
-    # multi-tenant EL serving: a FleetServer cohort (slot waves with
-    # mid-flight refill) vs sequential per-tenant sessions
-    # (scripts/bench_fleet.py in a subprocess — keeps this process's
-    # jax device config untouched)
-    if fleet:
-        rows.extend(_fleet_rows())
-
     if not quiet:
         for row in rows:
             print(f"micro {row['name']:40s} {row['us_per_call']:12.1f} us  "
@@ -214,97 +195,5 @@ def run(quiet: bool = False, sharded: bool = False,
     return rows
 
 
-def _sharded_rows() -> List[Dict]:
-    rows = []
-    import json as _json
-    import os as _os
-    import subprocess as _sp
-    import sys as _sys
-    import tempfile as _tempfile
-    repo = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
-    with _tempfile.TemporaryDirectory() as td:
-        bench_out = _os.path.join(td, "bench_el.json")
-        r = _sp.run(
-            [_sys.executable, _os.path.join(repo, "scripts", "bench_el.py"),
-             "--devices", "4", "--skip-host", "--repeats", "3",
-             "--samples", "2000", "--budget", "2000", "--max-rounds", "48",
-             "--max-events", "128", "--out", bench_out],
-            capture_output=True, text=True, timeout=1800,
-            env=dict(_os.environ,
-                     PYTHONPATH=_os.path.join(repo, "src")))
-        if r.returncode != 0:
-            raise RuntimeError(f"bench_el subprocess failed:\n{r.stdout}"
-                               f"\n{r.stderr}")
-        sub = _json.load(open(bench_out))["rows"]
-
-    def _peak(row):
-        p = row.get("peak_live_bytes")
-        return "n/a" if p is None else f"{p / 1e6:.2f}MB"
-
-    base = sub["el_sync_ingraph"]
-    for name, tag in (("el_sync_ingraph_donate", "donated"),
-                      ("el_sync_sharded", "sharded_2x2"),
-                      ("el_sync_sharded_donate", "sharded_donated")):
-        row = sub[name]
-        rows.append(dict(
-            name=f"{name}_per_round",
-            us_per_call=row["us_per_aggregation"],
-            derived=f"{tag},speedup={base['us_per_aggregation'] / max(row['us_per_aggregation'], 1e-9):.1f}"
-                    f"x_vs_replicated,peak={_peak(row)}"
-                    f"(vs{_peak(base)}),alias={row.get('alias_bytes', 0)}B"))
-    abase = sub["el_async_ingraph"]
-    arow = sub["el_async_sharded"]
-    rows.append(dict(
-        name="el_async_sharded_per_event",
-        us_per_call=arow["us_per_aggregation"],
-        derived=f"speedup={abase['us_per_aggregation'] / max(arow['us_per_aggregation'], 1e-9):.1f}"
-                f"x_vs_replicated,peak={_peak(arow)}(vs{_peak(abase)})"))
-    return rows
-
-
-def _fleet_rows() -> List[Dict]:
-    rows = []
-    import json as _json
-    import os as _os
-    import subprocess as _sp
-    import sys as _sys
-    import tempfile as _tempfile
-    repo = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
-    with _tempfile.TemporaryDirectory() as td:
-        bench_out = _os.path.join(td, "bench_fleet.json")
-        r = _sp.run(
-            [_sys.executable,
-             _os.path.join(repo, "scripts", "bench_fleet.py"),
-             "--tenants", "64", "--repeats", "1", "--out", bench_out],
-            capture_output=True, text=True, timeout=1800,
-            env=dict(_os.environ,
-                     PYTHONPATH=_os.path.join(repo, "src")))
-        if r.returncode != 0:
-            raise RuntimeError(f"bench_fleet subprocess failed:\n{r.stdout}"
-                               f"\n{r.stderr}")
-        sub = _json.load(open(bench_out))["rows"]
-    flt = sub["fleet_64"]
-    rows.append(dict(
-        name="fleet_tenants_per_sec",
-        us_per_call=1e6 / max(flt["tenants_per_sec"], 1e-9),
-        derived=f"{flt['tenants_per_sec']:.1f}t/s,"
-                f"speedup={flt['speedup_vs_sequential_host']:.1f}"
-                "x_vs_seq_host,"
-                f"{flt['speedup_vs_sequential_ingraph']:.1f}"
-                "x_vs_seq_ingraph,"
-                f"waves={flt['waves']},compiles={flt['compiles']}"))
-    return rows
-
-
 if __name__ == "__main__":
-    import argparse
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--sharded", action="store_true",
-                    help="also run the mesh-sharded/donated single-run "
-                         "rows (spawns a multi-device scripts/bench_el.py "
-                         "subprocess; minutes)")
-    ap.add_argument("--fleet", action="store_true",
-                    help="also run the multi-tenant fleet serving row "
-                         "(spawns a scripts/bench_fleet.py subprocess)")
-    _a = ap.parse_args()
-    run(sharded=_a.sharded, fleet=_a.fleet)
+    run()

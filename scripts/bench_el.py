@@ -67,7 +67,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 # must precede the jax import: the sharded rows need a real (CPU-
 # emulated) multi-device fleet, sized by --devices (default 4)
-from repro.launch.hostdev import force_host_devices
+from repro.launch.hostdev import force_host_devices, use_compile_cache
 
 force_host_devices("--devices", skip=(), count_from_flag=True,
                    always=True)
@@ -225,6 +225,7 @@ def main(argv=None) -> None:
     ap.add_argument("--no-history", action="store_true",
                     help="skip the BENCH_history.jsonl append")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     n_dev = jax.device_count()
     mesh = make_debug_mesh_for(n_dev)

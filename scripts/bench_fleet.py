@@ -40,7 +40,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 # must precede the jax import (keeps the env identical to bench_el.py;
 # the default rows run replicated, so the forced fleet is idle)
-from repro.launch.hostdev import force_host_devices
+from repro.launch.hostdev import force_host_devices, use_compile_cache
 
 force_host_devices("--devices", skip=(), count_from_flag=True,
                    always=True)
@@ -183,6 +183,7 @@ def main(argv=None) -> None:
     ap.add_argument("--no-history", action="store_true",
                     help="skip the BENCH_history.jsonl append")
     args = ap.parse_args(argv)
+    use_compile_cache()
     counts = [int(c) for c in args.tenants.split(",") if c]
 
     fx, base = _fixture(args)

@@ -26,10 +26,12 @@ with ``lax.top_k``, accepts the prefix of lanes that provably precede
 any block an earlier lane could reschedule (``wave_safe_gap`` — a
 rescheduled block costs at least ``fl(min_edge_cost · mult_floor)``, so
 every lane with ``f_(j) < fl(f_(0) + gap)`` is order-safe), runs the
-accepted lanes' local blocks as ONE vmapped dispatch over a slice-local
-``[K, ...]`` gather of the fetched-params stack, and replays the merge /
-bandit / schedule control plane sequentially per lane (masked
-``lax.cond``) so every computed value equals the one-event program's.
+accepted lanes' local blocks in one ``lax.map`` over a slice-local
+``[K, ...]`` gather of the fetched-params stack (per lane, not vmapped:
+a TPU rounds a batched block's minibatch sums differently), and replays
+the merge / bandit / schedule control plane sequentially per lane
+(masked ``lax.cond``) so every computed value equals the one-event
+program's.
 Wave lanes are always DISTINCT edges (one in-flight block per edge), the
 per-event RNG chain advances exactly ``n_batch`` splits, and history /
 telemetry writes coalesce into one drop-mode vector scatter per field —
@@ -357,12 +359,15 @@ def make_async_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
             k_costs.append(kc)
         rng = jnp.stack(rng_steps)[n_batch]
 
-        # -- data plane: ONE vmapped dispatch over the wave's lanes.
+        # -- data plane: the wave's lanes, one local block after another.
         # Lanes are distinct edges and each trains from the params its
         # edge fetched BEFORE this wave, so the lanes are data-
         # independent; only the K event slices of the sharded stack are
         # gathered replicated (slice-local), never the full [E, ...]
-        # edge stack.
+        # edge stack.  Not vmapped: on a TPU the batched block sums its
+        # minibatch in another order than the single-event block (the
+        # bias gradient differs in the last bit), and a wave must equal
+        # K single events bit for bit.
         interval_l = infl_i[e_sorted]                           # [Kw]
         cost_l = infl_c[e_sorted]
         # K scalar gathers, stacked — NOT one vector-index gather: the
@@ -377,8 +382,8 @@ def make_async_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
         data_keys = jnp.stack([
             jax.random.fold_in(k_datas[j], e_sorted[j])
             for j in range(batch_k)])
-        p_new_stack = jax.vmap(local_block)(p_stack, e_sorted,
-                                            interval_l, data_keys)
+        p_new_stack = lax.map(lambda lane: local_block(*lane),
+                              (p_stack, e_sorted, interval_l, data_keys))
 
         # -- control plane: the merge chain is inherently sequential
         # (lane j+1 merges into lane j's global), so replay it per lane

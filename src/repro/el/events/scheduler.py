@@ -111,13 +111,27 @@ def staleness_alpha(base, version, fetch_version, n_edges: int):
     return base / (1.0 + s)
 
 
+def _rounded(x):
+    """``x`` itself, held at its own f32 rounding: an add fed by this is
+    never contracted with the multiply that made ``x`` into one FMA.
+    ``max(x, 0) + min(x, 0)`` is exact (one side is zero), and neither
+    XLA nor LLVM folds it away — the signed twin of the ``maximum(...,
+    0.0)`` pin in :func:`schedule_block`."""
+    return jnp.maximum(x, 0.0) + jnp.minimum(x, 0.0)
+
+
 def staleness_merge(global_params, edge_params, alpha):
     """Masked asynchronous global update ``G <- (1-a)·G + a·θ_e`` (f32
     accumulation, cast back to the leaf dtype) — the jnp twin of
-    ``repro.federated.aggregation.staleness_mix`` with a traced alpha."""
+    ``repro.federated.aggregation.staleness_mix`` with a traced alpha.
+
+    Both products are pinned to their f32 rounding: XLA fuses the merge
+    with whatever surrounds it, and contracted ``a·b + c·d`` into an FMA
+    in the single-run program but not in the fleet's vmapped slot batch,
+    so a tenant drifted an ulp from its independent run."""
     def mix(g, e):
-        out = (1.0 - alpha) * g.astype(jnp.float32) \
-            + alpha * e.astype(jnp.float32)
+        out = (_rounded((1.0 - alpha) * g.astype(jnp.float32))
+               + _rounded(alpha * e.astype(jnp.float32)))
         return out.astype(g.dtype)
 
     return jax.tree.map(mix, global_params, edge_params)

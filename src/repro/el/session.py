@@ -374,7 +374,7 @@ class ELSession:
         report.telemetry = tele
         return report
 
-    def _profile_program(self, key: tuple, program: Any,
+    def _profile_program(self, key: tuple, program: Any, params: Params,
                          example_args: tuple, *, mode: str, mesh,
                          donate: bool, profile: bool, contract,
                          scenario: bool = False) -> Any:
@@ -387,7 +387,8 @@ class ELSession:
         ``profile`` / ``contract`` are the per-call opt-ins;
         ``REPRO_EL_PROFILE=1`` / ``REPRO_EL_CONTRACTS=1`` arm them
         process-wide.  ``contract=True`` checks the mode's
-        ``default_contract`` (collective census + donation aliasing);
+        ``default_contract`` (collective census + donation aliasing of
+        the placed ``params``' on-device bytes);
         a ``CollectiveContract`` instance checks that.  Violations
         raise ``repro.obs.prof.ContractViolation`` before dispatch.
         """
@@ -411,8 +412,7 @@ class ELSession:
                 c = obs_prof.default_contract(
                     mesh=mesh, donated=donate, mode=mode,
                     scenario=scenario,
-                    param_bytes=obs_prof.param_tree_bytes(
-                        example_args[0]))
+                    param_bytes=obs_prof.param_tree_bytes(params))
             c.enforce(prof)
         return prof
 
@@ -588,7 +588,7 @@ class ELSession:
                 self._cache_program(key, program)
         self._fastpath, self._fastpath_key = program, key
         self._profile_program(
-            key, program,
+            key, program, params,
             (jax.eval_shape(lambda p: p, params),
              jax.random.key(cfg.seed + 17), sync_knobs(cfg)),
             mode="sync", mesh=mesh, donate=donate, profile=profile,
@@ -691,7 +691,7 @@ class ELSession:
         if event_cap is not None:
             knobs["event_cap"] = np.int32(event_cap)
         self._profile_program(
-            key, program,
+            key, program, params,
             (jax.eval_shape(lambda p: p, params),
              jax.random.key(cfg.seed + 17), knobs),
             mode="async", mesh=mesh, donate=donate, profile=profile,

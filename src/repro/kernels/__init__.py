@@ -16,6 +16,15 @@ Kernels:
 
 
 def interpret_default() -> bool:
-    """Pallas kernels execute natively only on TPU; elsewhere interpret."""
+    """Native on TPU, the interpreter on the CPU (the test backend).
+
+    Any other backend is an error: a kernel quietly interpreted on an
+    accelerator would hide the device from every measurement."""
     import jax
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels here target TPU (native) or CPU "
+                       f"(interpret mode); backend {backend!r} is neither")

@@ -5,7 +5,10 @@ squared distances point x centroid + argmin.  Tiling: grid over point
 blocks (bn = 256 rows); the full centroid tile [K, D] stays resident in
 VMEM across the grid (K <= a few hundred for the paper's K=3..64 range).
 Distances use the matmul expansion ||x||^2 - 2 x.c + ||c||^2 so the inner
-product runs on the MXU.
+product runs on the MXU.  The outputs are ``[N, 1]`` columns: a 1-D
+``(bn,)`` block takes a layout Mosaic cannot match to XLA's for large N,
+and a vmapped call would put a non-tileable ``(.., bn)`` pair in the
+block's last two dims.
 """
 
 from __future__ import annotations
@@ -25,13 +28,14 @@ def _assign_kernel(x_ref, c_ref, out_ref, dist_ref):
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)      # [bn, 1]
     c2 = jnp.sum(c * c, axis=-1)[None, :]            # [1, K]
     d2 = x2 - 2.0 * xc + c2                          # [bn, K]
-    out_ref[...] = jnp.argmin(d2, axis=-1).astype(jnp.int32)
-    dist_ref[...] = jnp.min(d2, axis=-1)
+    out_ref[...] = jnp.argmin(d2, axis=-1, keepdims=True).astype(jnp.int32)
+    dist_ref[...] = jnp.min(d2, axis=-1, keepdims=True)
 
 
 def assign_fwd(x: jax.Array, centers: jax.Array, block_n: int = 256,
                interpret: bool = False):
-    """x: [N, D]; centers: [K, D] -> (assignments [N] i32, min_d2 [N] f32).
+    """x: [N, D]; centers: [K, D] -> (assignments [N, 1] i32,
+    min_d2 [N, 1] f32).
 
     N is padded to a block multiple by the ops wrapper.
     """
@@ -47,12 +51,12 @@ def assign_fwd(x: jax.Array, centers: jax.Array, block_n: int = 256,
             pl.BlockSpec((k, d), lambda i: (0, 0)),   # centroids resident
         ],
         out_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((bn, 1), lambda i: (i, 0)),
+            pl.BlockSpec((bn, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x, centers)

@@ -22,7 +22,7 @@ def assign_with_dist(x: jax.Array, centers: jax.Array,
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
     a, d2 = assign_fwd(x, centers, block_n=bn, interpret=interp)
-    return a[:n], d2[:n]
+    return a[:n, 0], d2[:n, 0]
 
 
 def assign(x: jax.Array, centers: jax.Array,

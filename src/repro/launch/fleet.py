@@ -19,7 +19,7 @@ programs — the CI smoke uses it to pin "one compile per cohort".
 
 from __future__ import annotations
 
-from repro.launch.hostdev import force_host_devices
+from repro.launch.hostdev import force_host_devices, use_compile_cache
 
 force_host_devices()     # must precede the jax import (emulated fleet)
 
@@ -112,7 +112,8 @@ def tenant_runs(manifest: Dict[str, Any], args) -> List[TenantRun]:
     return runs
 
 
-def main() -> None:
+def main(argv=None) -> Dict[str, Any]:
+    """Serve the manifest; returns the server's ``stats()``."""
     ap = argparse.ArgumentParser(
         description="serve a manifest of EL tenants as slot-batched "
                     "cohorts")
@@ -145,10 +146,11 @@ def main() -> None:
                     help="print every streamed round delta")
     add_metrics_args(ap)
     telemetry_arg(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.demo == (args.manifest is not None):
         ap.error("pass exactly one of --demo / --manifest")
+    use_compile_cache()
     manifest = DEMO_MANIFEST if args.demo else load_manifest(args.manifest)
 
     begin_observability(args)
@@ -222,6 +224,7 @@ def main() -> None:
               f"got {st['compiles']} (cohorts={st['cohorts']})",
               file=sys.stderr)
         raise SystemExit(1)
+    return st
 
 
 if __name__ == "__main__":

@@ -10,6 +10,10 @@ importing jax.  This module imports only ``os``/``sys`` (and the empty
 ``scripts/bench_el.py`` all route through here instead of keeping
 hand-rolled copies in sync.  (``repro.launch.dryrun`` keeps its own
 env-var preamble: it needs 512 placeholder devices unconditionally.)
+
+:func:`use_compile_cache` sits beside it: the launchers call it (never
+``import repro``), so each entry point reuses compiled programs across
+processes while the tests keep JAX's defaults.
 """
 
 from __future__ import annotations
@@ -17,6 +21,10 @@ from __future__ import annotations
 import os
 import sys
 from typing import Sequence
+
+#: the checkout root (``src/repro/launch/hostdev.py`` -> three levels up)
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def force_host_devices(flag: str = "--mesh", *,
@@ -48,3 +56,20 @@ def force_host_devices(flag: str = "--mesh", *,
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=" + n)
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX already reads it
+    and nothing is changed here.  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` — fixed because the path is part of the
+    cache key, so a directory that moved between runs would never hit.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = os.path.join(CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

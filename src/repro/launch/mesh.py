@@ -12,6 +12,14 @@ Target hardware (roofline constants in benchmarks/roofline.py):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the EL programs place their
+    data plane with ``lax.with_sharding_constraint``, which only accepts
+    Auto axes (``jax.make_mesh`` defaults to Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,13 +30,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     else:
         shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh for CPU multi-device tests (subprocesses set
     ``--xla_force_host_platform_device_count`` accordingly)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_debug_mesh_for(n_devices: int):

@@ -20,7 +20,7 @@ sweep (``data``) axis.
 
 from __future__ import annotations
 
-from repro.launch.hostdev import force_host_devices
+from repro.launch.hostdev import force_host_devices, use_compile_cache
 
 force_host_devices()     # must precede the jax import (emulated fleet)
 
@@ -110,6 +110,7 @@ def main() -> None:
     add_metrics_args(ap)
     telemetry_arg(ap)
     args = ap.parse_args()
+    use_compile_cache()
 
     from repro.el.scenarios.cli import scenario_from_args
     scenario, base_cost_model = scenario_from_args(args)

@@ -24,7 +24,7 @@ device with the smoke-scale configs.
 
 from __future__ import annotations
 
-from repro.launch.hostdev import force_host_devices
+from repro.launch.hostdev import force_host_devices, use_compile_cache
 
 force_host_devices()     # must precede the jax import (emulated fleet)
 
@@ -71,15 +71,15 @@ def _build_mesh(args):
     from repro.launch.mesh import make_debug_mesh_for, make_production_mesh
     if args.mesh == "debug":
         n_dev = jax.device_count()
-        if n_dev == 1:
+        if n_dev < 2:
             # the forced-host-device preamble scans sys.argv, so a
-            # programmatic main(argv=[... , "--mesh", "debug"]) call
-            # misses it — run unsharded loudly rather than silently
-            print("WARNING: --mesh debug but only 1 device is visible "
-                  "(forced host devices are set from sys.argv before "
-                  "jax init — invoke via the CLI, or set XLA_FLAGS="
-                  "--xla_force_host_platform_device_count=N yourself); "
-                  "running on a 1x1 mesh", flush=True)
+            # programmatic main(argv=[..., "--mesh", "debug"]) call
+            # misses it; a 1x1 "mesh" would be an unsharded run
+            raise SystemExit(
+                f"--mesh debug needs >= 2 devices, {n_dev} visible "
+                "(forced host devices are set from sys.argv before jax "
+                "init — invoke via the CLI, or set XLA_FLAGS="
+                "--xla_force_host_platform_device_count=N yourself)")
         return make_debug_mesh_for(n_dev)
     if not os.environ.get("REPRO_DEBUG_MESH") and jax.device_count() < 256:
         raise SystemExit(
@@ -90,11 +90,11 @@ def _build_mesh(args):
     return make_production_mesh()
 
 
-def train_classic_ol4el(exp, args) -> None:
-    """Classic archs through the compiled single-run EL programs —
-    optionally mesh-sharded (``--mesh``), buffer-donating
-    (``--donate``) and scenario-injected (``--churn``/``--cost-model``/
-    ``--drift``, see ``repro.el.scenarios``)."""
+def classic_session(args) -> ELSession:
+    """The session ``--mode ol4el`` builds for a classic arch: the
+    ``classic_fixture`` data plane under the CLI's config (exposed so a
+    caller can replay the same run on another path, e.g. the host
+    reference ``run_async(rng_streams="jax")``)."""
     from repro.el.scenarios.cli import scenario_from_args
     from repro.launch.classic import classic_fixture
 
@@ -111,11 +111,19 @@ def train_classic_ol4el(exp, args) -> None:
                              policy="ol4el", utility=fx["utility"],
                              cost_model=base_cost_model,
                              scenario=scenario)
+    return (ELSession(ol, metric_name=metric, lr=fx["lr"])
+            .with_executor(fx["executor"], init_params=fx["init_params"],
+                           n_samples=fx["n_samples"]))
+
+
+def train_classic_ol4el(exp, args):
+    """Classic archs through the compiled single-run EL programs —
+    optionally mesh-sharded (``--mesh``), buffer-donating
+    (``--donate``) and scenario-injected (``--churn``/``--cost-model``/
+    ``--drift``, see ``repro.el.scenarios``)."""
+    session = classic_session(args)
+    ol, metric = session.cfg, session.metric_name
     mesh = _build_mesh(args)
-    session = (ELSession(ol, metric_name=metric, lr=fx["lr"])
-               .with_executor(fx["executor"],
-                              init_params=fx["init_params"],
-                              n_samples=fx["n_samples"]))
     desc = (f"compiled {ol.mode} run, {args.edges} edges"
             + (f", mesh {tuple(mesh.shape.items())}" if mesh else "")
             + (", donated params" if args.donate else ""))
@@ -198,7 +206,7 @@ def train_ol4el(exp, args) -> None:
     return report
 
 
-def main(argv=None) -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -242,24 +250,33 @@ def main(argv=None) -> None:
                     choices=["jnp", "pallas"],
                     help="K-means E-step engine for the local blocks "
                          "(pallas: the repro.kernels.kmeans_assign "
-                         "kernel; interpret mode off-TPU)")
+                         "kernel; native on TPU, interpret mode on the CPU)")
     from repro.el.scenarios.cli import add_scenario_args
     add_scenario_args(ap)
     add_metrics_args(ap, trace_dir=True)
     telemetry_arg(ap)
     args = ap.parse_args(argv)
-
-    exp = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    classic_el = args.mode == "ol4el" and exp.model.family == "classic"
+    family = get_config(args.arch).model.family
     scenario_flags = (args.churn is not None or args.drift is not None
                       or args.cost_model not in ("fixed", "variable"))
-    if not classic_el and (args.mesh != "none" or args.donate
-                          or args.telemetry is not None or scenario_flags):
+    if (not (args.mode == "ol4el" and family == "classic")
+            and (args.mesh != "none" or args.donate
+                 or args.telemetry is not None or scenario_flags)):
         ap.error("--mesh/--donate/--telemetry/--churn/--drift and the "
                  "scenario --cost-model kinds drive the compiled "
                  "single-run programs, which need a classic arch under "
                  "--mode ol4el (LM archs and --mode standard run the "
                  "host loops)")
+    return args
+
+
+def main(argv=None):
+    """Run the launcher; returns the run's ``ELReport`` (``None`` for
+    ``--mode standard``)."""
+    args = parse_args(argv)
+    use_compile_cache()
+    exp = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    classic_el = args.mode == "ol4el" and exp.model.family == "classic"
     begin_observability(args)
     if args.mode == "standard":
         report = train_standard(exp, args)
@@ -273,6 +290,7 @@ def main(argv=None) -> None:
         registry = registry_from_report(
             report, labels={"arch": args.arch, "mode": report.mode})
     finish_observability(args, registry)
+    return report
 
 
 if __name__ == "__main__":

@@ -85,7 +85,7 @@ class KMeans:
     ``impl`` selects the E-step engine, following the ``models/layers``
     convention: ``"jnp"`` (the pure-XLA distance expansion) or
     ``"pallas"`` — the ``repro.kernels.kmeans_assign`` Pallas kernel
-    (native on TPU, interpret mode elsewhere; oracle-tested against the
+    (native on TPU, interpret mode on the CPU; oracle-tested against the
     jnp path in tests/test_kernels.py).  The kernel is vmap-safe, so the
     compiled EL programs' per-edge local blocks route through it too.
     ``use_kernel=True`` is the deprecated spelling of ``impl="pallas"``.

@@ -49,8 +49,12 @@ _DTYPE_BYTES = {
 _SHAPE_RE = re.compile(r"(pred|bf16|f16|f32|f64|s8|u8|s16|u16|s32|u32|s64|"
                        r"u64|c64|c128)\[([0-9,]*)\]")
 
+_CUSTOM_CALL_RE = re.compile(r'custom_call_target="([^"]+)"')
+
+#: ``%x = <result type> <op>(`` — the type may carry a TPU tiled layout
+#: (``f32[16,8]{1,0:T(8,128)S(1)}``) but never an ``=``
 _COLLECTIVE_RE = re.compile(
-    r"=\s+(\(?[a-z0-9\[\],{}\s]+?\)?)\s+"
+    r"=\s+([^=]+?)\s+"
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute)(?:-start)?\(")
 
@@ -91,6 +95,16 @@ def parse_collectives(hlo_text: str) -> Dict[str, Any]:
         d["bytes"] += nbytes
     total = sum(d["bytes"] for d in per_op.values())
     return {"per_op": per_op, "bytes_per_device": total}
+
+
+def parse_custom_calls(hlo_text: str) -> Dict[str, int]:
+    """Count the optimized HLO's custom calls by target — a natively
+    compiled Pallas TPU kernel shows up as ``tpu_custom_call``; an
+    interpreted one leaves none."""
+    counts: Dict[str, int] = {}
+    for target in _CUSTOM_CALL_RE.findall(hlo_text):
+        counts[target] = counts.get(target, 0) + 1
+    return counts
 
 
 # ---------------------------------------------------------------------------
@@ -138,7 +152,8 @@ class ProgramProfile:
 
     All fields are best-effort (``None`` when the backend withholds the
     analysis); ``collectives`` maps op mnemonic → ``{"count", "bytes"}``
-    with per-device result bytes (see :func:`parse_collectives`).
+    with per-device result bytes (see :func:`parse_collectives`), and
+    ``custom_calls`` maps custom-call target → count (Pallas kernels).
     ``peak_live_bytes`` is the bench convention: arguments + outputs +
     temps − aliased, per device.
     """
@@ -155,6 +170,7 @@ class ProgramProfile:
     collectives: Dict[str, Dict[str, float]] = \
         dataclasses.field(default_factory=dict)
     collective_bytes: int = 0
+    custom_calls: Dict[str, int] = dataclasses.field(default_factory=dict)
     hlo_lines: Optional[int] = None
     backend: Optional[str] = None
     donated: bool = False
@@ -226,6 +242,7 @@ def profile_compiled(compiled, *, donated: bool = False) -> ProgramProfile:
         census = parse_collectives(hlo)
         kw["collectives"] = census["per_op"]
         kw["collective_bytes"] = int(census["bytes_per_device"])
+        kw["custom_calls"] = parse_custom_calls(hlo)
         kw["hlo_lines"] = hlo.count("\n")
     except Exception as e:                                  # pragma: no cover
         errors.append(f"hlo: {e}")
@@ -310,8 +327,11 @@ class CollectiveContract:
 
     def check(self, profile: ProgramProfile) -> List[str]:
         """The list of violations (empty when the profile satisfies the
-        contract)."""
-        bad: List[str] = []
+        contract).  A profile that could not read part of the executable
+        (``profile.errors``) violates every contract: a census it never
+        took would otherwise read as zero collectives."""
+        bad: List[str] = [f"profile incomplete: {e}"
+                          for e in profile.errors]
         for op, want in sorted(dict(self.counts).items()):
             msg = _check_count(op, profile.collective_count(op), want)
             if msg is not None:
@@ -333,15 +353,21 @@ class CollectiveContract:
 
 
 def param_tree_bytes(tree: Any) -> int:
-    """Total bytes of a params tree (shapes x itemsize) — the donated
-    side of the alias contract.  Accepts concrete arrays or
-    ``ShapeDtypeStruct`` trees."""
+    """Per-device bytes of a params tree — the donated side of the alias
+    contract.  A placed ``jax.Array`` counts its first shard's on-device
+    size, layout padding included (a TPU stores f32 ``[59, 8]`` in whole
+    tiles, and ``memory_analysis`` reports aliased bytes the same way);
+    anything else (numpy, ``ShapeDtypeStruct``) counts shape x itemsize.
+    """
     import jax
     import numpy as np
     total = 0
     for leaf in jax.tree.leaves(tree):
-        total += int(np.prod(np.shape(leaf), dtype=np.int64)
-                     * np.dtype(leaf.dtype).itemsize)
+        if isinstance(leaf, jax.Array):
+            total += int(leaf.addressable_data(0).on_device_size_in_bytes())
+        else:
+            total += int(np.prod(np.shape(leaf), dtype=np.int64)
+                         * np.dtype(leaf.dtype).itemsize)
     return total
 
 

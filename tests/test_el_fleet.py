@@ -170,9 +170,9 @@ def test_sync_fleet_bit_identical_with_refill(svm):
     (forces multi-wave runs): every report — records, params, pulls —
     equals an independent run_sync_ingraph of that tenant alone, and the
     streamed deltas ARE the report's records."""
-    cfgs = [_cfg(svm, "sync", 900.0, 1.0, 0),
+    cfgs = [_cfg(svm, "sync", 1200.0, 1.0, 0),
             _cfg(svm, "sync", 1500.0, 0.5, 1),
-            _cfg(svm, "sync", 600.0, 2.0, 2)]
+            _cfg(svm, "sync", 900.0, 2.0, 2)]
     srv, ids, reports, deltas, _ = _serve(svm, cfgs, 2, 5)
     st = srv.stats()
     assert st["compiles"] == 1                   # one cohort, one program

@@ -304,3 +304,47 @@ def test_kmeans_impl_validation_and_back_compat():
         build_model(cfg, impl="cuda")
     assert build_model(cfg, use_kernel=True).impl == "pallas"
     assert build_model(cfg).impl == "jnp"
+
+
+# ---------------------------------------------------------------------------
+# launch plumbing: Auto-axis meshes, no 1x1 fallback, the compile cache
+# ---------------------------------------------------------------------------
+
+
+def test_launch_meshes_have_auto_axes(monkeypatch):
+    """``with_sharding_constraint`` only accepts Auto mesh axes, and
+    ``jax.make_mesh`` defaults to Explicit: both launch meshes must ask
+    for Auto."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_debug_mesh, make_production_mesh
+    monkeypatch.setenv("REPRO_DEBUG_MESH", "1")
+    for mesh in (make_debug_mesh(1, 1), make_production_mesh()):
+        assert mesh.axis_names == ("data", "model")
+        assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
+def test_train_debug_mesh_on_one_device_is_an_error(monkeypatch):
+    import argparse
+
+    from repro.launch.train import _build_mesh
+    # a worker that imported repro.launch.dryrun sees its placeholder
+    # fleet: pin the count this test is about
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    with pytest.raises(SystemExit, match="needs >= 2 devices"):
+        _build_mesh(argparse.Namespace(mesh="debug"))
+
+
+def test_use_compile_cache_env_wins_else_fixed_checkout_dir(monkeypatch):
+    from repro.launch.hostdev import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert use_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before   # set nothing
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = use_compile_cache()
+        assert path == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

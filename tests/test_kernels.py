@@ -148,3 +148,17 @@ def test_kmeans_assign_vs_ref(n, d, k, dtype):
                                atol=1e-2, rtol=1e-2)
     if dtype == jnp.float32:
         assert (np.asarray(a) == np.asarray(a_ref)).mean() > 0.999
+
+
+def test_interpret_default_native_on_tpu_interpreted_on_cpu_only(
+        monkeypatch):
+    """The interpreter is the CPU test path only: on TPU kernels run
+    natively, and any other backend is an error rather than a kernel
+    quietly interpreted on an accelerator."""
+    from repro.kernels import interpret_default
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert interpret_default() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        interpret_default()

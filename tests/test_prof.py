@@ -82,6 +82,22 @@ def test_parse_collectives_counts_start_once_skips_done():
     assert obs_prof.parse_collectives("no collectives here")["per_op"] == {}
 
 
+def test_parse_collectives_tpu_tiled_layouts():
+    """TPU HLO prints tiled layouts (``{1,0:T(8,128)S(1)}``) in result
+    types; the census must still see those collectives, and a fusion
+    that merely consumes one is not a collective."""
+    hlo = textwrap.dedent("""\
+        %all-gather.5 = f32[16,8]{1,0:T(8,128)S(1)} all-gather(%g.1), channel_id=2, dimensions={0}, metadata={op_name="jit(program)/while/body/sharding_constraint"}
+        %all-gather.10 = f32[16,59,8]{1,2,0:T(8,128)S(1)} all-gather(%g.2), channel_id=1, frontend_attributes={async_collective_name="all-gather-start"}
+        %f = f32[59,8]{0,1:T(8,128)S(1)} fusion(%all-gather.10, %c), kind=kLoop, calls=%fused
+        %ar = (f32[8]{0:T(128)}, f32[8]{0:T(128)}) all-reduce-start(%a, %b), to_apply=%sum
+    """)
+    census = obs_prof.parse_collectives(hlo)
+    assert census["per_op"]["all-gather"] == {
+        "count": 2, "bytes": 16 * 8 * 4 + 16 * 59 * 8 * 4}
+    assert census["per_op"]["all-reduce"] == {"count": 1, "bytes": 64}
+
+
 # -- ProgramProfile + contracts (pure) --------------------------------------
 
 
@@ -128,6 +144,28 @@ def test_collective_contract_check_and_enforce():
                for m in alias.check(_profile(alias_bytes=None)))
 
 
+def test_contract_violated_by_incomplete_profile():
+    """A census the profile never took must not read as "0 collectives":
+    the replicated contract fails on a profile whose HLO was unreadable."""
+    unread = _profile(alias_bytes=0, errors=("hlo: no text",))
+    contract = obs_prof.default_contract()
+    assert contract.check(_profile(alias_bytes=0)) == []
+    assert contract.check(unread) == ["profile incomplete: hlo: no text"]
+    with pytest.raises(obs_prof.ContractViolation, match="incomplete"):
+        contract.enforce(unread)
+
+
+def test_parse_custom_calls_synthetic_hlo():
+    hlo = textwrap.dedent("""\
+        %k1 = (s32[256,1]{1,0}) custom-call(f32[256,64]{1,0} %x), custom_call_target="tpu_custom_call", backend_config={}
+        %k2 = (s32[256,1]{1,0}) custom-call(f32[256,64]{1,0} %y), custom_call_target="tpu_custom_call"
+        %s = f32[4]{0} custom-call(f32[4]{0} %z), custom_call_target="Sharding"
+    """)
+    assert obs_prof.parse_custom_calls(hlo) == {"tpu_custom_call": 2,
+                                                "Sharding": 1}
+    assert obs_prof.parse_custom_calls("no kernels here") == {}
+
+
 def test_default_contract_shapes():
     # no mesh: a replicated program may issue NO collectives, alias 0
     c = obs_prof.default_contract()
@@ -156,6 +194,11 @@ def test_param_tree_bytes():
     tree = {"w": jax.ShapeDtypeStruct((4, 59), jnp.float32),
             "b": np.zeros((3,), np.int32)}
     assert obs_prof.param_tree_bytes(tree) == 4 * 59 * 4 + 3 * 4
+    # a placed array counts its on-device size (== logical on the CPU;
+    # a TPU adds its tile padding)
+    placed = jax.tree.map(jnp.asarray, tree["b"])
+    assert obs_prof.param_tree_bytes(placed) == \
+        placed.addressable_data(0).on_device_size_in_bytes() == 12
 
 
 # -- live extraction (AOT lower/compile on the real backend) ----------------

@@ -1,0 +1,93 @@
+"""Compile the main path's Pallas kernel for a described TPU v5e.
+
+No chip is needed: the TPU compiler builds for a topology that is
+described, not attached, and refuses what the chip would refuse (block
+shapes that do not tile, layouts Mosaic cannot match).  Interpret-mode
+tests cannot see those faults.  The topology is described inside a
+fixture, never at import, so every pytest worker collects the same tests
+and only the worker that runs this file loads the TPU compiler.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from repro.kernels.kmeans_assign.ops import assign_with_dist
+from repro.obs.prof import parse_collectives
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # no logs under /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
+def _hlo(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _native(x, c):
+    return assign_with_dist(x, c, interpret=False)
+
+
+@pytest.mark.parametrize("n_edges,n,d,k", [
+    (16, 128, 64, 3),      # the local block: per-edge minibatch, vmapped
+    (16, 128, 64, 8),
+])
+def test_kmeans_assign_compiles_vmapped_over_edges(one_chip, n_edges, n, d,
+                                                   k):
+    x = jax.ShapeDtypeStruct((n_edges, n, d), jnp.float32,
+                             sharding=one_chip)
+    c = jax.ShapeDtypeStruct((n_edges, k, d), jnp.float32,
+                             sharding=one_chip)
+    assert "tpu_custom_call" in _hlo(jax.vmap(_native), x, c)
+
+
+@pytest.mark.parametrize("n,d,k", [
+    (20000, 64, 3),        # the paper's full dataset, padded to the block
+    (4096, 64, 3),
+    (128, 64, 3),
+])
+def test_kmeans_assign_compiles_whole_dataset(one_chip, n, d, k):
+    x = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)
+    c = jax.ShapeDtypeStruct((k, d), jnp.float32, sharding=one_chip)
+    assert "tpu_custom_call" in _hlo(_native, x, c)
+
+
+def test_collective_census_reads_tpu_hlo(mesh_2x2):
+    """The contracts' census parses the TPU compiler's HLO, whose result
+    types carry tiled layouts: an edge stack sharded over ``data`` and
+    gathered before the cross-edge sum shows its all-gather and no
+    all-reduce (gather-before-reduce, as the sharded EL programs do)."""
+    def aggregate(stack):
+        full = lax.with_sharding_constraint(stack,
+                                            NamedSharding(mesh_2x2, P()))
+        return full.sum(axis=0)
+
+    stack = jax.ShapeDtypeStruct((16, 59, 8), jnp.float32,
+                                 sharding=NamedSharding(mesh_2x2, P("data")))
+    census = parse_collectives(_hlo(aggregate, stack))["per_op"]
+    assert census["all-gather"]["count"] >= 1
+    assert "all-reduce" not in census
