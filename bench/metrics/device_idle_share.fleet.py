@@ -1,0 +1,6 @@
+"""``device_idle_share`` of the fleet cells: the same reading, split by
+name because it moves ``report_latency_p95_ms`` there."""
+
+from benchlib import load_named
+
+read = load_named("metrics", "device_idle_share").read
