@@ -24,10 +24,10 @@ class ProgramCache:
     count ``get()``/``put()`` outcomes — a fleet cohort compiles exactly
     once iff every later lookup of its key is a hit — and are surfaced
     as a snapshot by :meth:`stats` (``ELReport.telemetry["cache"]``,
-    the fleet CLI summary line).  Lookups and evictions also emit
-    ``cache.hit`` / ``cache.miss`` / ``cache.evict`` events on the
-    process tracer (``repro.obs.trace``), so a JSONL span stream shows
-    exactly when a server recompiled.
+    the fleet CLI summary line).  An eviction also emits a
+    ``cache.evict`` event on the process tracer (``repro.obs.trace``),
+    since it marks a later recompile; a session's lookup outcome rides
+    on its ``session.prepare`` span as ``cache="hit"`` / ``"miss"``.
     """
 
     def __init__(self, max_entries: int = 8):
@@ -43,14 +43,11 @@ class ProgramCache:
         self.evictions = 0
 
     def get(self, key: tuple, default: Optional[Any] = None) -> Any:
-        from repro.obs import trace
         entry = self._entries.get(key, default)
         if entry is default:
             self.misses += 1
-            trace.event("cache.miss", misses=self.misses)
         else:
             self.hits += 1
-            trace.event("cache.hit", hits=self.hits)
         return entry
 
     def put(self, key: tuple, program: Any) -> Any:

@@ -21,6 +21,7 @@ this class.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
@@ -39,6 +40,22 @@ from repro.el.report import (ELReport, RoundRecord, records_from_out,
 
 Params = Any
 RoundCallback = Callable[[RoundRecord], None]
+
+
+def _session_call(mode: str):
+    """Wrap a compiled entry point in its root ``session.call`` span
+    (``repro.obs.trace``): the stage spans the call opens inside —
+    ``session.prepare`` (with ``session.compile`` on a cache miss),
+    ``session.dispatch``, ``session.records``, ``session.evaluate``,
+    ``session.report`` — share its ``call`` id."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(self, *args, **kwargs):
+            from repro.obs import trace as obs_trace
+            with obs_trace.span("session.call", mode=mode):
+                return fn(self, *args, **kwargs)
+        return call
+    return wrap
 
 
 class ELSession:
@@ -511,6 +528,28 @@ class ELSession:
                 jax.eval_shape(lambda p: p, params), knob_names)
         return jax.jit(core, **kw)
 
+    def _finish_ingraph(self, ex: EdgeExecutor, cfg: OL4ELConfig,
+                        key: tuple, out: Dict[str, Any], params: Params,
+                        mode: str, horizon: int, t0: float) -> ELReport:
+        """The host half of a compiled single run after its dispatch,
+        one stage span each: the round records (and the callbacks), the
+        final evaluation, the report."""
+        from repro.obs import trace as obs_trace
+        with obs_trace.span("session.records") as sp:
+            records: List[RoundRecord] = []
+            for rec in records_from_out(out, 0, int(out["n_rounds"])):
+                self._emit(records, rec)
+            sp["n"] = len(records)
+        with obs_trace.span("session.evaluate", n=1):
+            final = ex.evaluate(params)[self.metric_name]
+        with obs_trace.span("session.report"):
+            report = report_from_out(
+                out, mode=mode, policy=cfg.policy, horizon=horizon,
+                final_metric=final, final_params=params,
+                elapsed_s=time.perf_counter() - t0, records=records)
+            return self._attach_cache_stats(report, key)
+
+    @_session_call("sync")
     def run_sync_ingraph(self, max_rounds: int = 512,
                          metric_fn: Optional[Callable] = None, *,
                          mesh=None, donate: bool = False,
@@ -566,48 +605,46 @@ class ELSession:
         from repro.el.ingraph import (make_sync_program, sync_knob_names,
                                       sync_knobs)
         from repro.obs import rings as obs_rings, trace as obs_trace
-        ex = self._require_executor()
-        cfg = self._ingraph_cfg("run_sync_ingraph", mode="sync")
-        spec = obs_rings.as_spec(telemetry)
-        t0 = time.perf_counter()
-        key = ("sync", ex, self._structural_cfg(cfg), max_rounds,
-               metric_fn, self.metric_name,
-               None if self._n_samples is None else tuple(self._n_samples),
-               mesh, donate, spec)
-        params = self._initial_params()
-        program = self._programs.get(key)
-        if program is None:
-            with obs_trace.span("session.compile", mode="sync",
-                                telemetry=spec is not None):
-                program = self._jit_ingraph(make_sync_program(
-                    ex.model, ex.edge_data, ex.eval_set, cfg,
-                    lr=ex.lr, batch=ex.batch, n_samples=self._n_samples,
-                    metric_fn=metric_fn, metric_name=self.metric_name,
-                    max_rounds=max_rounds, mesh=mesh, telemetry=spec),
-                    sync_knob_names(cfg), mesh, donate, params)
-                self._cache_program(key, program)
-        self._fastpath, self._fastpath_key = program, key
-        self._profile_program(
-            key, program, params,
-            (jax.eval_shape(lambda p: p, params),
-             jax.random.key(cfg.seed + 17), sync_knobs(cfg)),
-            mode="sync", mesh=mesh, donate=donate, profile=profile,
-            contract=contract, scenario=cfg.scenario is not None)
+        with obs_trace.span("session.prepare") as sp:
+            ex = self._require_executor()
+            cfg = self._ingraph_cfg("run_sync_ingraph", mode="sync")
+            spec = obs_rings.as_spec(telemetry)
+            t0 = time.perf_counter()
+            key = ("sync", ex, self._structural_cfg(cfg), max_rounds,
+                   metric_fn, self.metric_name,
+                   None if self._n_samples is None
+                   else tuple(self._n_samples),
+                   mesh, donate, spec)
+            params = self._initial_params()
+            program = self._programs.get(key)
+            sp["cache"] = "miss" if program is None else "hit"
+            if program is None:
+                with obs_trace.span("session.compile", mode="sync",
+                                    telemetry=spec is not None):
+                    program = self._jit_ingraph(make_sync_program(
+                        ex.model, ex.edge_data, ex.eval_set, cfg,
+                        lr=ex.lr, batch=ex.batch,
+                        n_samples=self._n_samples, metric_fn=metric_fn,
+                        metric_name=self.metric_name,
+                        max_rounds=max_rounds, mesh=mesh, telemetry=spec),
+                        sync_knob_names(cfg), mesh, donate, params)
+                    self._cache_program(key, program)
+            self._fastpath, self._fastpath_key = program, key
+            self._profile_program(
+                key, program, params,
+                (jax.eval_shape(lambda p: p, params),
+                 jax.random.key(cfg.seed + 17), sync_knobs(cfg)),
+                mode="sync", mesh=mesh, donate=donate, profile=profile,
+                contract=contract, scenario=cfg.scenario is not None)
         with obs_trace.span("session.dispatch", mode="sync") as sp:
             params, out = jax.block_until_ready(
                 program(params, jax.random.key(cfg.seed + 17),
                         sync_knobs(cfg)))
             sp["n_rounds"] = int(out["n_rounds"])
-        records: List[RoundRecord] = []
-        for rec in records_from_out(out, 0, int(out["n_rounds"])):
-            self._emit(records, rec)
-        final = ex.evaluate(params)[self.metric_name]
-        report = report_from_out(
-            out, mode="sync", policy=cfg.policy, horizon=max_rounds,
-            final_metric=final, final_params=params,
-            elapsed_s=time.perf_counter() - t0, records=records)
-        return self._attach_cache_stats(report, key)
+        return self._finish_ingraph(ex, cfg, key, out, params, "sync",
+                                    max_rounds, t0)
 
+    @_session_call("async")
     def run_async_ingraph(self, max_events: Optional[int] = None,
                           metric_fn: Optional[Callable] = None, *,
                           mesh=None, donate: bool = False,
@@ -655,64 +692,61 @@ class ELSession:
                                      make_async_program,
                                      padded_event_horizon)
         from repro.obs import rings as obs_rings, trace as obs_trace
-        ex = self._require_executor()
-        cfg = self._ingraph_cfg("run_async_ingraph", mode="async")
-        spec = obs_rings.as_spec(telemetry)
-        t0 = time.perf_counter()
-        if max_events is None:
-            # the padded (power-of-two) horizon: it is part of the
-            # compile cache key (it sizes the history arrays), so keying
-            # the exact budget/cost-dependent value would recompile on
-            # every knob change the traced inputs exist to absorb
-            horizon = padded_event_horizon(cfg)
-            event_cap = None
-        else:
-            # explicit caps bucket the same way: the STATIC history
-            # length is the pow-2 envelope, the exact cap is the traced
-            # event_cap knob — nearby caps share one executable
-            event_cap = int(max_events)
-            horizon = bucket_event_horizon(event_cap)
-        key = ("async", ex, self._structural_cfg(cfg), horizon, metric_fn,
-               self.metric_name, mesh, donate, spec)
-        params = self._initial_params()
-        program = self._programs.get(key)
-        if program is None:
-            with obs_trace.span("session.compile", mode="async",
-                                telemetry=spec is not None):
-                program = self._jit_ingraph(make_async_program(
-                    ex.model, ex.edge_data, ex.eval_set, cfg,
-                    lr=ex.lr, batch=ex.batch, metric_fn=metric_fn,
-                    metric_name=self.metric_name, max_events=horizon,
-                    mesh=mesh, telemetry=spec),
-                    async_knob_names(cfg), mesh, donate, params)
-                self._cache_program(key, program)
-        self._async_fastpath, self._async_key = program, key
-        knobs = async_knobs(cfg)
-        if event_cap is not None:
-            knobs["event_cap"] = np.int32(event_cap)
-        self._profile_program(
-            key, program, params,
-            (jax.eval_shape(lambda p: p, params),
-             jax.random.key(cfg.seed + 17), knobs),
-            mode="async", mesh=mesh, donate=donate, profile=profile,
-            contract=contract, scenario=cfg.scenario is not None)
+        with obs_trace.span("session.prepare") as sp:
+            ex = self._require_executor()
+            cfg = self._ingraph_cfg("run_async_ingraph", mode="async")
+            spec = obs_rings.as_spec(telemetry)
+            t0 = time.perf_counter()
+            if max_events is None:
+                # the padded (power-of-two) horizon: it is part of the
+                # compile cache key (it sizes the history arrays), so
+                # keying the exact budget/cost-dependent value would
+                # recompile on every knob change the traced inputs exist
+                # to absorb
+                horizon = padded_event_horizon(cfg)
+                event_cap = None
+            else:
+                # explicit caps bucket the same way: the STATIC history
+                # length is the pow-2 envelope, the exact cap is the
+                # traced event_cap knob — nearby caps share one executable
+                event_cap = int(max_events)
+                horizon = bucket_event_horizon(event_cap)
+            key = ("async", ex, self._structural_cfg(cfg), horizon,
+                   metric_fn, self.metric_name, mesh, donate, spec)
+            params = self._initial_params()
+            program = self._programs.get(key)
+            sp["cache"] = "miss" if program is None else "hit"
+            if program is None:
+                with obs_trace.span("session.compile", mode="async",
+                                    telemetry=spec is not None):
+                    program = self._jit_ingraph(make_async_program(
+                        ex.model, ex.edge_data, ex.eval_set, cfg,
+                        lr=ex.lr, batch=ex.batch, metric_fn=metric_fn,
+                        metric_name=self.metric_name, max_events=horizon,
+                        mesh=mesh, telemetry=spec),
+                        async_knob_names(cfg), mesh, donate, params)
+                    self._cache_program(key, program)
+            self._async_fastpath, self._async_key = program, key
+            knobs = async_knobs(cfg)
+            if event_cap is not None:
+                knobs["event_cap"] = np.int32(event_cap)
+            self._profile_program(
+                key, program, params,
+                (jax.eval_shape(lambda p: p, params),
+                 jax.random.key(cfg.seed + 17), knobs),
+                mode="async", mesh=mesh, donate=donate, profile=profile,
+                contract=contract, scenario=cfg.scenario is not None)
         with obs_trace.span("session.dispatch", mode="async") as sp:
             params, out = jax.block_until_ready(
                 program(params, jax.random.key(cfg.seed + 17), knobs))
             sp["n_events"] = int(out["n_rounds"])
-        records: List[RoundRecord] = []
-        for rec in records_from_out(out, 0, int(out["n_rounds"])):
-            self._emit(records, rec)
-        final = ex.evaluate(params)[self.metric_name]
-        report = report_from_out(
-            out, mode="async", policy=cfg.policy,
-            horizon=horizon if event_cap is None else event_cap,
-            final_metric=final, final_params=params,
-            elapsed_s=time.perf_counter() - t0, records=records)
-        return self._attach_cache_stats(report, key)
+        return self._finish_ingraph(
+            ex, cfg, key, out, params, "async",
+            horizon if event_cap is None else event_cap, t0)
 
     # -- compiled ablation sweeps ---------------------------------------------
 
+    @_session_call("sweep")
     def sweep(self, spec, *, mesh=None,
               metric_fn: Optional[Callable] = None, telemetry=None):
         """Run a whole ablation grid as ONE compiled, vmapped program.
@@ -740,45 +774,51 @@ class ELSession:
         from repro.el.sweep.engine import (make_sweep_program,
                                            run_sweep_program)
         from repro.el.sweep.report import SweepReport
-        from repro.obs import rings as obs_rings
-        ex = self._require_executor()
-        cfg = self._ingraph_cfg("ELSession.sweep")
-        tele_spec = obs_rings.as_spec(telemetry)
-        t0 = time.perf_counter()
-        from repro.obs import trace as obs_trace
-        # each async_batch_k value is a different compiled wave body —
-        # run one vmapped sub-sweep per K (a single-K / sync grid is one
-        # sub-sweep: exactly the old path)
-        subs = (spec.per_batch_k() if cfg.mode == "async"
-                else [(None, spec)])
+        from repro.obs import rings as obs_rings, trace as obs_trace
+        with obs_trace.span("session.prepare") as sp:
+            ex = self._require_executor()
+            cfg = self._ingraph_cfg("ELSession.sweep")
+            tele_spec = obs_rings.as_spec(telemetry)
+            t0 = time.perf_counter()
+            # each async_batch_k value is a different compiled wave body —
+            # run one vmapped sub-sweep per K (a single-K / sync grid is
+            # one sub-sweep: exactly the old path)
+            subs = (spec.per_batch_k() if cfg.mode == "async"
+                    else [(None, spec)])
+            runs, missed = [], False
+            for k_val, sub in subs:
+                sub_cfg = (cfg if k_val is None else dataclasses.replace(
+                    cfg, async_batch_k=int(k_val)))
+                # the jitted vmapped program only depends on the
+                # structural config (incl. async_batch_k), the grid SHAPE
+                # (axis lengths fix the [n_cells] dim and, with a mesh,
+                # the input shardings) and max_rounds — not the knob
+                # values
+                axes = sub.axes(sub_cfg)
+                spec_shape = (tuple(len(v) for v in axes.values()),
+                              sub.max_rounds)
+                key = ("sweep", ex, self._structural_cfg(sub_cfg),
+                       spec_shape, metric_fn, self.metric_name, mesh,
+                       None if self._n_samples is None
+                       else tuple(self._n_samples),
+                       tele_spec)
+                program = self._programs.get(key)
+                if program is None:
+                    missed = True
+                    with obs_trace.span("session.compile", mode="sweep",
+                                        n_cells=sub.n_cells):
+                        program = make_sweep_program(
+                            ex.model, ex.edge_data, ex.eval_set, sub_cfg,
+                            sub, lr=ex.lr, batch=ex.batch,
+                            n_samples=self._n_samples, metric_fn=metric_fn,
+                            metric_name=self.metric_name,
+                            mesh=mesh, telemetry=tele_spec)
+                        self._cache_program(key, program)
+                self._sweep_program, self._sweep_key = program, key
+                runs.append((sub, sub_cfg, program))
+            sp["cache"] = "miss" if missed else "hit"
         params_parts, out_parts = [], []
-        for k_val, sub in subs:
-            sub_cfg = (cfg if k_val is None else dataclasses.replace(
-                cfg, async_batch_k=int(k_val)))
-            # the jitted vmapped program only depends on the structural
-            # config (incl. async_batch_k), the grid SHAPE (axis lengths
-            # fix the [n_cells] dim and, with a mesh, the input
-            # shardings) and max_rounds — not the knob values
-            axes = sub.axes(sub_cfg)
-            spec_shape = (tuple(len(v) for v in axes.values()),
-                          sub.max_rounds)
-            key = ("sweep", ex, self._structural_cfg(sub_cfg), spec_shape,
-                   metric_fn, self.metric_name, mesh,
-                   None if self._n_samples is None
-                   else tuple(self._n_samples),
-                   tele_spec)
-            program = self._programs.get(key)
-            if program is None:
-                with obs_trace.span("session.compile", mode="sweep",
-                                    n_cells=sub.n_cells):
-                    program = make_sweep_program(
-                        ex.model, ex.edge_data, ex.eval_set, sub_cfg, sub,
-                        lr=ex.lr, batch=ex.batch,
-                        n_samples=self._n_samples, metric_fn=metric_fn,
-                        metric_name=self.metric_name,
-                        mesh=mesh, telemetry=tele_spec)
-                    self._cache_program(key, program)
-            self._sweep_program, self._sweep_key = program, key
+        for sub, sub_cfg, program in runs:
             with obs_trace.span("session.dispatch", mode="sweep",
                                 n_cells=sub.n_cells):
                 params, out = run_sweep_program(
@@ -786,25 +826,28 @@ class ELSession:
                     sub.cell_cfgs(sub_cfg))
             params_parts.append(params)
             out_parts.append(out)
-        if len(out_parts) == 1:
-            params, out = params_parts[0], out_parts[0]
-        else:
-            # async_batch_k is slowest-varying, so concatenating the
-            # sub-sweeps along the cell axis reproduces spec.cells()
-            params = jax.tree.map(
-                lambda *xs: jax.numpy.concatenate(xs, axis=0),
-                *params_parts)
-            out = jax.tree.map(
-                lambda *xs: np.concatenate(xs, axis=0), *out_parts)
-        report = SweepReport(
-            spec=spec, axes=spec.axes(cfg), cells=spec.cells(cfg),
-            out=out, policy=cfg.policy,
-            elapsed_s=time.perf_counter() - t0, final_params=params)
+        with obs_trace.span("session.report"):
+            if len(out_parts) == 1:
+                params, out = params_parts[0], out_parts[0]
+            else:
+                # async_batch_k is slowest-varying, so concatenating the
+                # sub-sweeps along the cell axis reproduces spec.cells()
+                params = jax.tree.map(
+                    lambda *xs: jax.numpy.concatenate(xs, axis=0),
+                    *params_parts)
+                out = jax.tree.map(
+                    lambda *xs: np.concatenate(xs, axis=0), *out_parts)
+            report = SweepReport(
+                spec=spec, axes=spec.axes(cfg), cells=spec.cells(cfg),
+                out=out, policy=cfg.policy,
+                elapsed_s=time.perf_counter() - t0, final_params=params)
         # workloads without a jittable metric (e.g. K-means F1) run the
         # program with NaN metric history; score the final params host-side
         # so the report's frontier still has an accuracy axis
-        report.score_final_params(
-            lambda p: ex.evaluate(p)[self.metric_name])
+        with obs_trace.span("session.evaluate") as sp:
+            scored = report.score_final_params(
+                lambda p: ex.evaluate(p)[self.metric_name])
+            sp["n"] = report.n_cells if scored else 0
         return report
 
     # -- AC-sync estimator plumbing -------------------------------------------
