@@ -533,9 +533,15 @@ class ELSession:
                         mode: str, horizon: int, t0: float) -> ELReport:
         """The host half of a compiled single run after its dispatch,
         one stage span each: the round records (and the callbacks), the
-        final evaluation, the report."""
+        final evaluation, the report.  Both are built from one host copy
+        of ``out``: ``device_get`` starts every leaf's transfer before
+        waiting on any, where reading a device array element by element
+        costs a gather and a blocking copy per element.  ``params``
+        stays on the device for ``ex.evaluate``."""
         from repro.obs import trace as obs_trace
         with obs_trace.span("session.records") as sp:
+            out = jax.device_get(out)
+            sp["host_bytes"] = sum(x.nbytes for x in jax.tree.leaves(out))
             records: List[RoundRecord] = []
             for rec in records_from_out(out, 0, int(out["n_rounds"])):
                 self._emit(records, rec)

@@ -6,6 +6,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.el import ELSession, SweepSpec
@@ -206,3 +207,51 @@ def test_session_call_holds_its_stage_spans(entry, tracer, request):
             e for e in tracer.events() if e["name"] == "session.dispatch"
             and e["call"] == calls[1][0]["id"]).get(
                 "n_rounds" if entry == "sync" else "n_events")
+
+
+@pytest.mark.parametrize("mode", ("sync", "async"))
+def test_records_are_built_from_one_host_copy(mode, svm, tracer,
+                                              monkeypatch):
+    """A compiled run's records and report come from one host copy of
+    its ``out``: the builders see numpy leaves, one ``device_get`` a
+    call, and the result equals, bit for bit, what the builders make
+    from the program's device arrays."""
+    from repro.el import session as el_session
+    seen, copies = [], []
+    build, device_get = el_session.records_from_out, jax.device_get
+
+    def spy_records(out, lo, hi):
+        seen.append(dict(out))
+        return build(out, lo, hi)
+
+    def spy_get(tree):
+        copies.append(tree)
+        return device_get(tree)
+
+    monkeypatch.setattr(el_session, "records_from_out", spy_records)
+    monkeypatch.setattr(jax, "device_get", spy_get)
+    report = ENTRY_POINTS[mode][1](_session(svm, mode))
+    (host,), (dev,) = seen, copies
+    assert set(host) >= {"wall", "consumed", "metric", "utility",
+                         "interval", "n_rounds", "arm_pulls"}
+    assert ("edge" in host) == (mode == "async")
+    for leaf in jax.tree.leaves(host):
+        assert isinstance(leaf, np.ndarray)
+    assert any(isinstance(leaf, jax.Array) for leaf in jax.tree.leaves(dev))
+
+    def bits(recs):
+        return np.array([dataclasses.astuple(r) for r in recs],
+                        np.float64).view(np.uint64)
+
+    ref = build(dev, 0, int(dev["n_rounds"]))
+    assert len(ref) == report.n_aggregations > 0
+    np.testing.assert_array_equal(bits(report.records), bits(ref))
+    ref_report = el_session.report_from_out(   # horizon: ENTRY_POINTS' 32
+        dev, mode=mode, policy=report.policy, horizon=32,
+        final_metric=report.final_metric, final_params=None, elapsed_s=0.0)
+    for k in ("n_aggregations", "total_consumed", "wall_time",
+              "terminated_reason", "arm_pulls"):
+        assert getattr(report, k) == getattr(ref_report, k), k
+    span, = tracer.events("session.records")
+    assert span["host_bytes"] == sum(
+        leaf.nbytes for leaf in jax.tree.leaves(dev)) > 0
