@@ -1,13 +1,16 @@
-"""The control of the correctness check as a test can run it without a
-chip: the plain reference put in the program's place and computed the
-way the program's control computes on the chip — float32 values, every
-matrix product in one bfloat16 pass (``benchlib.prec``'s ``default``),
-the step below the configurations' float32 at ``high``.  It runs free —
-its own decisions from its own utilities — and its records go through
-the same comparison as the program's: the float64 reference replays them
-and ``check.compare`` reads the five numbers.  A sound limit lets the
-program through and stops the control.  On the chip the control is the
-program itself at ``default`` precision (``bench/control.py``).
+"""The control of the correctness check: the plain reference put in the
+program's place and computed the way the program's control computes on
+the chip — every matrix product in one bfloat16 pass (JAX's
+``default`` on a TPU), the step below the configurations' float32 at
+``high`` or ``highest``.  The configuration's check kind gives both
+sides (``check.kind``): ``host-f64`` runs it in numpy on the host
+(float32 values, ``benchlib.prec``'s ``default``), ``device-f32`` on the
+device.  It runs free — its own decisions from its own utilities — and
+its records go through the same comparison as the program's: the
+check's reference replays them and ``check.compare`` reads the five
+numbers.  A sound limit lets the program through and stops the control.
+On the chip the control is also the program itself at ``default``
+precision (``bench/control.py``).
 """
 
 from __future__ import annotations
@@ -16,9 +19,8 @@ from typing import Dict, List
 
 import numpy as np
 
-from benchlib import check, data, elref
+from benchlib import check, elref
 from benchlib.drive import Draws
-from benchlib.prec import DEFAULT, F64
 
 
 def run_specs(cfg: dict, traffic: dict, seed: int, n: int) -> List[dict]:
@@ -47,21 +49,20 @@ def run_specs(cfg: dict, traffic: dict, seed: int, n: int) -> List[dict]:
             for i in range(n)]
 
 
-def readings(cfg: dict, ref, traffic: dict, seed: int, n: int,
-             precision=DEFAULT) -> Dict[str, float]:
-    """The five numbers of ``n`` control runs (worst over the runs)."""
-    edges, test = data.make(cfg)
-    low = elref.Workload(cfg, ref, edges, test, precision)
-    high = elref.Workload(cfg, ref, edges, test, F64)
+def readings(cfg: dict, ref, traffic: dict, seed: int, n: int
+             ) -> Dict[str, float]:
+    """The five numbers of ``n`` control runs (worst over the runs): the
+    configuration's check reference at its control precision, in the
+    program's place, replayed by the same check."""
+    kind = check.kind(cfg)
+    low = kind.workload(cfg, ref, control=True)
+    high = kind.workload(cfg, ref)
     init = ref.init(cfg, int(Draws(seed, 0).seeds(1)[0]))
     rows = []
     for run in run_specs(cfg, traffic, seed, n):
-        run = dict(run, init=init)
-        sim = (elref.simulate_sync if run["mode"] == "sync"
-               else elref.simulate_async)
-        rec = sim(low, run)["record"]
+        rec = elref.simulate(low, dict(run, init=init))["record"]
         rec["final_params"] = {k: np.asarray(v, np.float32)
                                for k, v in rec["final_params"].items()}
-        rows.append(check.compare(rec, sim(high, run, forced=rec),
-                                  run["budget"], cfg["n_edges"]))
+        rows.append(check.replay_numbers(
+            cfg, high, {"run": run, "record": rec, "init": init}))
     return check.worst(rows)

@@ -2,7 +2,11 @@
 
 Counted: the matrix products of the model's local step (forward and
 backward for the SVM, the two products of a Lloyd step for K-means),
-the aggregation, and the per-aggregation eval metric.  Elementwise work
+the aggregation, and the per-aggregation eval metric.  What belongs to
+one model (a step's products, its parameters, its metric) is counted by
+the configuration's reference (``bench/configs/<name>.py``:
+``step_flops``, ``n_params``, ``eval_flops``), so a new model brings its
+counts in its own file.  Elementwise work
 is left out, and so are the steps a compiled block masks past its
 interval: they are waste, not required work.
 
@@ -21,29 +25,25 @@ def step_flops(cfg: dict, ref) -> float:
     return float(ref.step_flops(cfg))
 
 
-def n_params(cfg: dict) -> int:
-    d, c = cfg["features"], cfg["classes"]
-    return d * c + c if cfg["model"] == "svm" else d * c
+def n_params(cfg: dict, ref) -> int:
+    """The model's parameters, as its reference counts them."""
+    return int(ref.n_params(cfg))
 
 
-def eval_flops(cfg: dict) -> float:
-    """The per-aggregation metric: the SVM's accuracy over the held-out
-    rows; K-means's parameter-delta utility (its F1 is host work)."""
-    if cfg["utility"] == "eval_gain":
-        n_eval = int(cfg["data"]["samples"] * cfg["data"]["test_frac"])
-        return 2.0 * n_eval * cfg["features"] * cfg["classes"]
-    return 3.0 * n_params(cfg)
+def eval_flops(cfg: dict, ref) -> float:
+    """The per-aggregation metric, as the reference counts it."""
+    return float(ref.eval_flops(cfg))
 
 
 def sync_round_flops(cfg: dict, ref, interval: int) -> float:
     e = cfg["n_edges"]
-    return (e * interval * step_flops(cfg, ref) + 2.0 * e * n_params(cfg)
-            + eval_flops(cfg))
+    return (e * interval * step_flops(cfg, ref)
+            + 2.0 * e * n_params(cfg, ref) + eval_flops(cfg, ref))
 
 
 def async_event_flops(cfg: dict, ref, interval: int) -> float:
-    return (interval * step_flops(cfg, ref) + 3.0 * n_params(cfg)
-            + eval_flops(cfg))
+    return (interval * step_flops(cfg, ref) + 3.0 * n_params(cfg, ref)
+            + eval_flops(cfg, ref))
 
 
 def required_flops(cfg: dict, ref, mode: str, intervals: Iterable[float]

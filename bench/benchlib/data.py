@@ -3,7 +3,9 @@ and their non-IID split over edges, made from a configuration's
 ``data_seed``.
 
 Each data kind's recipe is ``bench/datasets/<kind>.py`` (wafer: the
-SVM's Gaussian class clusters; traffic: K-means's Gaussian mixture); the
+SVM's Gaussian class clusters; traffic: K-means's Gaussian mixture),
+which returns its rows in the dtypes the model takes them (float32
+features, int32 labels; token rows would be int32); the
 held-out split and the non-IID split over edges (a Dirichlet(alpha) draw
 of class proportions per edge) are here.  All are copied from the
 program's ``repro.data.classic_data``, so that the data the reference
@@ -30,8 +32,7 @@ def _split(rng, x, y, test_frac) -> Tuple[Split, Split]:
     n_test = int(len(y) * test_frac)
     idx = rng.permutation(len(y))
     tr, te = idx[n_test:], idx[:n_test]
-    return ({"x": x[tr].astype(np.float32), "y": y[tr].astype(np.int32)},
-            {"x": x[te].astype(np.float32), "y": y[te].astype(np.int32)})
+    return {"x": x[tr], "y": y[tr]}, {"x": x[te], "y": y[te]}
 
 
 def dirichlet_edges(data: Split, n_edges: int, alpha: float, seed: int

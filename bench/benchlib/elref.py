@@ -21,11 +21,23 @@ Async event (one bandit per edge):
     pays its block's cost, updates its bandit, refetches the global
     params and schedules its next block if its budget allows.
 
+The model's arithmetic is the workload's (``round``, ``event``,
+``mix``, ``metric``, ``utility``, ``params``, ``host``): ``Workload``
+here computes it in numpy at a chosen precision from the configuration's
+reference; ``bench/checks/device-f32.py`` computes it on the device.
+The control plane (selection weights, costs, the ledger, the streams) is
+computed here, at the workload's precision ``P``.
+
 ``simulate_*`` runs free (its own decisions: the control) or follows a
 recorded run's decisions (``forced``: the check).  Following, it still
 draws its own choice at every decision and reports how far its own best
 arm lies above the recorded one (``select_gap``), so a wrong decision
 shows even though the replay goes on along the recorded path.
+
+``replay`` follows a record once with a reference whose local step
+takes an assignment (``local_step(..., pick=)``, K-means) and names its
+``TIE_WIDTH``: it logs each near tie that the step meets (``Ties``) and
+assigns the ones it is given the other way.
 """
 
 from __future__ import annotations
@@ -207,6 +219,10 @@ class Workload:
         self.eval = {"x": P.arr(eval_set["x"]),
                      "y": np.asarray(eval_set["y"])}
         self.w_agg = self.n / self.n.sum()
+        #: the reference's tie width, and the ``Ties`` of the replay
+        #: under way (``replay``)
+        self.tie_width = getattr(model, "TIE_WIDTH", None)
+        self.ties: Optional[Ties] = None
 
     def rows(self, edges: np.ndarray, u: np.ndarray):
         """Minibatch rows drawn by uniforms ``u`` [len(edges), B]: row =
@@ -219,9 +235,37 @@ class Workload:
               u: np.ndarray) -> Params:
         """``interval`` local steps on each of ``edges`` (``p`` leaves
         ``[len(edges), ...]``; ``u`` ``[len(edges), k, B]``)."""
+        kw = {} if self.ties is None else {"pick": self.ties}
         for s in range(interval):
             x, y = self.rows(edges, u[:, s])
-            p = self.model.local_step(self.P, self.cfg, p, x, y)
+            p = self.model.local_step(self.P, self.cfg, p, x, y, **kw)
+        return p
+
+    def params(self, init: Params) -> Params:
+        """The run's initial parameters at this precision."""
+        return {k: self.P.arr(v) for k, v in init.items()}
+
+    def round(self, p: Params, interval: int, u: np.ndarray) -> Params:
+        """A sync round's model work: every edge runs ``interval`` steps
+        from ``p`` (``u`` ``[E, k, B]``), then the n_e-weighted mean."""
+        E = self.cfg["n_edges"]
+        local = self.block(_stack(p, E), np.arange(E), interval, u)
+        return {k: self.P.r(np.einsum("e...,e->...", v, self.w_agg))
+                for k, v in local.items()}
+
+    def event(self, p: Params, e: int, interval: int, u: np.ndarray
+              ) -> Params:
+        """An async event's model work: edge ``e`` runs ``interval``
+        steps from ``p`` (``u`` ``[k, B]``)."""
+        local = self.block(_stack(p, 1), np.array([e]), interval, u[None])
+        return {k: v[0] for k, v in local.items()}
+
+    def mix(self, g: Params, p: Params, alpha: float) -> Params:
+        """The staleness-weighted merge ``(1 - alpha) g + alpha p``."""
+        return _mix(self.P, g, p, alpha)
+
+    def host(self, p: Params) -> Params:
+        """``p`` as host arrays (a reference on the device copies)."""
         return p
 
     def metric(self, p: Params) -> float:
@@ -233,6 +277,51 @@ class Workload:
         sq = sum(float(np.sum((self.P.r(new[k] - old[k])) ** 2))
                  for k in new)
         return 1.0 / (1.0 + math.sqrt(sq))
+
+
+class Ties:
+    """An assignment ``pick(d2, scale)`` for a reference's local step:
+    the argmin of the distances ``d2`` ``[..., K]``, logging each point
+    whose two nearest centroids lie within ``width`` times its terms'
+    ``scale`` (``|x|^2 + 2|x.c| + |c|^2``, on which float32's rounding
+    of the program's distances rests) as ``(call, point)``, and
+    assigning the points in ``flips`` to their second-nearest centroid
+    instead."""
+
+    def __init__(self, width: float, flips=frozenset()):
+        self.width, self.flips = width, frozenset(flips)
+        self.met: List[tuple] = []
+        self.calls = 0
+
+    def __call__(self, d2: np.ndarray, scale: np.ndarray) -> np.ndarray:
+        order = np.argsort(d2, axis=-1, kind="stable").reshape(
+            -1, d2.shape[-1])
+        flat = d2.reshape(-1, d2.shape[-1])
+        rows = np.arange(len(flat))
+        margin = flat[rows, order[:, 1]] - flat[rows, order[:, 0]]
+        near = np.flatnonzero(margin < self.width
+                              * scale.reshape(len(flat), -1).max(axis=-1))
+        a = order[:, 0].copy()
+        for i in near:
+            key = (self.calls, int(i))
+            self.met.append(key)
+            if key in self.flips:
+                a[i] = order[i, 1]
+        self.calls += 1
+        return a.reshape(d2.shape[:-1])
+
+
+def replay(wl, run: dict, record: dict, flips=frozenset()):
+    """``(simulate(wl, run, forced=record), met)``: the replay along a
+    record and the near ties it met (``Ties``; none where ``wl`` names no
+    tie width), with the ties in ``flips`` assigned the other way."""
+    if getattr(wl, "tie_width", None) is None:
+        return simulate(wl, run, record), []
+    wl.ties = Ties(wl.tie_width, flips)
+    try:
+        return simulate(wl, run, record), wl.ties.met
+    finally:
+        wl.ties = None
 
 
 def _stack(p: Params, n: int) -> Params:
@@ -263,7 +352,7 @@ def simulate_sync(wl: Workload, run: dict,
                                                 horizon)
     gum, uni = sync_streams(run["seed"], max(n_plan, 1), E, K, B, K)
 
-    params = {k: P.arr(v) for k, v in run["init"].items()}
+    params = wl.params(run["init"])
     counts, usum = np.zeros(K, np.int64), np.zeros(K)
     t_pulls = 0
     consumed = np.zeros(E)
@@ -272,7 +361,6 @@ def simulate_sync(wl: Workload, run: dict,
     out = {k: [] for k in ("interval", "metric", "utility", "consumed",
                            "wall")}
     gap = 0.0
-    edges = np.arange(E)
     t = 0
     while True:
         resid = P.r(budget - consumed)
@@ -288,9 +376,7 @@ def simulate_sync(wl: Workload, run: dict,
                       else int(forced["interval"][t]) - 1)
         gap = max(gap, g)
         interval = arm + 1
-        local = wl.block(_stack(params, E), edges, interval, uni[t])
-        new = {k: P.r(np.einsum("e...,e->...", v, wl.w_agg))
-               for k, v in local.items()}
+        new = wl.round(params, interval, uni[t])
         slot = float(np.max(P.r(interval * c["comp"] + c["comm"])))
         consumed = P.r(consumed + slot)
         wall = float(P.r(wall + slot))
@@ -309,8 +395,8 @@ def simulate_sync(wl: Workload, run: dict,
                     and (budget - consumed).min() >= costs.min() - 1e-12
                     and not np.any(budget - consumed < c["min_cost"]))
     rec = {k: np.asarray(v, np.float64) for k, v in out.items()}
-    rec.update(n=t, final_params=params, final_metric=wl.metric(params),
-               edge=None)
+    rec.update(n=t, final_params=wl.host(params),
+               final_metric=wl.metric(params), edge=None)
     # a recorded run that stopped while the reference would go on (or
     # went on past the reference's stop) differs in its count
     extra = int(forced is not None and would_go)
@@ -370,15 +456,14 @@ def simulate_async(wl: Workload, run: dict,
         for key in out:
             out[key].append(rec_vals[key])
     rec = {k: np.asarray(v, np.float64) for k, v in out.items()}
-    rec.update(n=len(out["edge"]), final_params=st["g"],
+    rec.update(n=len(out["edge"]), final_params=wl.host(st["g"]),
                final_metric=wl.metric(st["g"]))
     return {"record": rec, "select_gap": gap, "count_gap": mismatches}
 
 
 def _async_state(wl, run, E, K):
-    P = wl.P
-    g = {k: P.arr(v) for k, v in run["init"].items()}
-    return {"P": P, "g": g, "fetched": [g] * E,
+    g = wl.params(run["init"])
+    return {"P": wl.P, "g": g, "fetched": [g] * E,
             "counts": np.zeros((E, K), np.int64), "usum": np.zeros((E, K)),
             "tp": np.zeros(E, np.int64), "consumed": np.zeros(E),
             "finish": np.full(E, np.inf), "infl_i": np.zeros(E, np.int64),
@@ -415,13 +500,11 @@ def _event(wl, st, c, alpha0, e, u) -> Dict[str, float]:
     P, E = wl.P, wl.cfg["n_edges"]
     wall = float(st["finish"][e])
     interval, cost = int(st["infl_i"][e]), float(st["infl_c"][e])
-    local = wl.block(_stack(st["fetched"][e], 1), np.array([e]), interval,
-                     u[None])
-    p_new = {k: v[0] for k, v in local.items()}
+    p_new = wl.event(st["fetched"][e], e, interval, u)
     st["consumed"][e] = P.r(st["consumed"][e] + cost)
     alpha = float(P.r(alpha0 / P.r(
         1.0 + P.r((st["version"] - st["fetch_ver"][e]) / E))))
-    new = _mix(P, st["g"], p_new, alpha)
+    new = wl.mix(st["g"], p_new, alpha)
     st["version"] += 1
     m = wl.metric(new) if wl.cfg["utility"] == "eval_gain" else math.nan
     u_val = wl.utility(new, st["g"], m, st["prev"])
@@ -463,5 +546,13 @@ def _async_free(wl, run, c, costs_ek) -> Dict[str, Any]:
             out[key].append(vals[key])
         t += 1
     rec = {k: np.asarray(v, np.float64) for k, v in out.items()}
-    rec.update(n=t, final_params=st["g"], final_metric=wl.metric(st["g"]))
+    rec.update(n=t, final_params=wl.host(st["g"]),
+               final_metric=wl.metric(st["g"]))
     return {"record": rec, "select_gap": 0.0, "count_gap": 0}
+
+
+def simulate(wl, run: dict, forced: Optional[dict] = None
+             ) -> Dict[str, Any]:
+    """``simulate_sync`` or ``simulate_async``, by the run's ``mode``."""
+    sim = simulate_sync if run["mode"] == "sync" else simulate_async
+    return sim(wl, run, forced)

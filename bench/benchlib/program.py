@@ -2,54 +2,51 @@
 
 The program is driven only through its library entry points
 (``ELSession``, ``FleetServer``); the benchmark hands it the data, the
-initial parameters and the knobs, and takes back its reports.  Widths in
-the configuration file are checked against the program's own
+initial parameters, the knobs and, for a cell on more than one chip, a
+mesh, and takes back its reports.  How a configuration becomes a program
+is found by name: the configuration file's ``program`` key names
+``bench/programs/<kind>.py`` (``classic`` where the key is absent), whose
+``build(cfg, init, mesh)`` returns the pieces the drivers use
+(``executor``, ``base``, ``n_samples``, ``init``, ``metric``).  Each
+kind checks the configuration's widths against the program's own
 configuration of the same architecture: a mismatch is an error, never a
 silent resize.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import numpy as np
 
-from benchlib import data
+from benchlib import load_named
 
 
-def build(cfg: dict, init: Dict[str, np.ndarray]) -> Dict[str, Any]:
-    """The executor, the base run config and the placed initial params."""
-    import jax.numpy as jnp
+def build(cfg: dict, init: Dict[str, np.ndarray], mesh=None
+          ) -> Dict[str, Any]:
+    """The program of ``cfg``'s kind, with ``mesh`` (``None`` on one
+    chip) under ``fx["mesh"]`` for the drivers."""
+    kind = load_named("programs", cfg.get("program", "classic"))
+    fx = kind.build(cfg, init, mesh)
+    fx["mesh"] = mesh
+    return fx
 
-    from repro.config import OL4ELConfig, get_config
-    from repro.federated import ClassicExecutor
-    from repro.models import build_model
 
-    exp = get_config(cfg["arch"])
-    if (exp.model.d_model, exp.model.vocab_size) != (cfg["features"],
-                                                     cfg["classes"]):
-        raise ValueError(
-            f"{cfg['name']}: the program's {cfg['arch']} has widths "
-            f"({exp.model.d_model}, {exp.model.vocab_size}), the "
-            f"configuration ({cfg['features']}, {cfg['classes']})")
-    # the configuration names the model's build arguments by the keys
-    # of its own numbers, so that one number feeds program and reference
-    model = build_model(exp.model, **{arg: cfg[key] for arg, key in
-                                      cfg["model_args"].items()})
-    edges, test = data.make(cfg)
-    ex = ClassicExecutor(model, edges, test, batch=cfg["batch"],
-                         lr=cfg["lr"])
-    base = OL4ELConfig(
-        max_interval=cfg["max_interval"], mode="sync", cost_model="fixed",
-        policy="ol4el", budget=float(cfg["budget"]),
-        comp_cost=float(cfg["comp_cost"]), comm_cost=float(cfg["comm_cost"]),
-        heterogeneity=float(cfg["heterogeneity"]), utility=cfg["utility"],
-        async_alpha=float(cfg["async_alpha"]), async_batch_k=0,
-        ucb_c=float(cfg["ucb_c"]), n_edges=cfg["n_edges"], seed=0)
-    return {"executor": ex, "base": base,
-            "n_samples": [len(e["y"]) for e in edges],
-            "init": {k: jnp.asarray(v) for k, v in init.items()},
-            "metric": cfg["metric"]}
+def mesh_for(cfg: dict, n_chips: int) -> Optional[Any]:
+    """The mesh of a cell on ``n_chips`` chips, built by the program's
+    own Auto-axis builder over the first ``n_chips`` devices: ``None`` on
+    one chip, so a one-chip cell runs the program's mesh-less path;
+    otherwise every chip on the edge (``data``) axis, unless the
+    configuration file states its axes as ``"mesh": {"data": d,
+    "model": m}``."""
+    if n_chips == 1:
+        return None
+    from repro.launch.mesh import make_debug_mesh
+    axes = cfg.get("mesh", {"data": n_chips, "model": 1})
+    if axes["data"] * axes["model"] != n_chips:
+        raise ValueError(f"{cfg['name']}: mesh {axes} does not cover the "
+                         f"cell's {n_chips} chips")
+    return make_debug_mesh(axes["data"], axes["model"])
 
 
 def record_from_report(rep) -> Dict[str, Any]:

@@ -6,7 +6,10 @@ form — device op intervals and host spans, in nanoseconds on one clock —
 and every number is computed from that form by the functions below, so a
 small trace written out by hand in the tests checks the arithmetic:
 
-  busy            the union of the device op intervals inside the window;
+  busy            the union of one device's op intervals inside the
+                  window, averaged over the devices (``by_device``): the
+                  busy time of one chip of the cell's, which ``busy_s``
+                  and every busy or idle reading take;
   idle share      1 - busy / window;
   op totals       device seconds of the innermost ops by op name (a TPU
                   trace names an op by its HLO text, ``%fusion.12 = ...``:
@@ -19,6 +22,11 @@ small trace written out by hand in the tests checks the arithmetic:
   idle gaps       each stretch of the window in which no op runs, named by
                   the innermost host span open at its midpoint, totalled
                   by that name.
+
+Op totals and idle gaps are a device's; ``mean_totals`` averages them
+over the devices, as busy is.  ``device``, every device's ops together,
+serves the readings that add work up over the chips, such as a kernel's
+seconds.
 """
 
 from __future__ import annotations
@@ -65,26 +73,28 @@ def discard(cap: Dict[str, Optional[str]]) -> None:
 
 
 def load(path: str) -> Dict[str, object]:
-    """``{"device": [...], "host": [...], "meta": {...}, "devices": n}``
-    from an xplane file: op events of every device plane's op line, every
-    host thread event (TraceAnnotation spans among them), and for each
-    device op name the text of its event stats (the HLO op's metadata,
-    such as the ``pallas_call`` a kernel came from, and its shapes)."""
+    """``{"device": [...], "by_device": [[...], ...], "host": [...],
+    "meta": {...}}`` from an xplane file: op events of
+    every device plane's op line (all planes together, and plane by
+    plane), every host thread event (TraceAnnotation spans among them),
+    and for each device op name the text of its event stats (the HLO
+    op's metadata, such as the ``pallas_call`` a kernel came from, and
+    its shapes)."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    device: List[Interval] = []
+    by_device: List[List[Interval]] = []
     host: List[Interval] = []
     meta: Dict[str, str] = {}
-    n_dev = 0
     for plane in pd.planes:
         if plane.name.startswith("/device:"):
             lines = [ln for ln in plane.lines if ln.name == DEVICE_OP_LINE]
             if lines:
-                n_dev += 1
+                by_device.append([])
             for line in lines:
                 for ev in line.events:
                     s = int(ev.start_ns)
-                    device.append((s, s + int(ev.duration_ns), ev.name))
+                    by_device[-1].append((s, s + int(ev.duration_ns),
+                                          ev.name))
                     if ev.name not in meta:
                         meta[ev.name] = " ".join(
                             f"{k}={v}" for k, v in ev.stats)
@@ -93,8 +103,11 @@ def load(path: str) -> Dict[str, object]:
                 for ev in line.events:
                     s = int(ev.start_ns)
                     host.append((s, s + int(ev.duration_ns), ev.name))
-    return {"device": device, "host": host, "meta": meta,
-            "devices": max(n_dev, 1)}
+    # a trace with no device plane (a CPU run) reads as one idle device
+    by_device = by_device or [[]]
+    device = [ev for evs in by_device for ev in evs]
+    return {"device": device, "by_device": by_device, "host": host,
+            "meta": meta}
 
 
 def union(intervals: Sequence[Interval], lo: int, hi: int
@@ -113,6 +126,26 @@ def union(intervals: Sequence[Interval], lo: int, hi: int
 
 def busy_ns(device: Sequence[Interval], lo: int, hi: int) -> int:
     return sum(e - s for s, e in union(device, lo, hi))
+
+
+def busy_ns_per_device(by_device: Sequence[Sequence[Interval]], lo: int,
+                       hi: int) -> float:
+    """``busy_ns`` of each device's own ops, averaged over the devices:
+    the busy time of one chip of several (one device: ``busy_ns``)."""
+    return (sum(busy_ns(evs, lo, hi) for evs in by_device)
+            / max(len(by_device), 1))
+
+
+def mean_totals(per_device: Sequence[Sequence[Tuple[str, float]]]
+                ) -> List[Tuple[str, float]]:
+    """Totals by name (``op_totals``, ``idle_gaps``) of each device,
+    averaged over the devices, largest first."""
+    tot: Dict[str, float] = {}
+    for rows in per_device:
+        for k, v in rows:
+            tot[k] = tot.get(k, 0.0) + v
+    n = max(len(per_device), 1)
+    return sorted(((k, v / n) for k, v in tot.items()), key=lambda kv: -kv[1])
 
 
 def op_name(name: str) -> str:

@@ -23,20 +23,33 @@ def init(cfg: dict, seed: int) -> dict:
         size=(cfg["classes"], cfg["features"])).astype(np.float32)}
 
 
-def _assign(P, x: np.ndarray, c: np.ndarray) -> np.ndarray:
-    d2 = P.r(P.r(np.sum(x * x, axis=-1, keepdims=True))
-             - 2.0 * P.mm(x, np.swapaxes(c, -1, -2))
-             + P.r(np.sum(c * c, axis=-1))[..., None, :])
-    return np.argmin(d2, axis=-1)
+#: two centroids whose distances from a point differ by less than this
+#: share of the distances' terms (``|x|^2 + 2|x.c| + |c|^2``) are tied
+#: for the program's float32 E-step, 16 times float32's epsilon: its
+#: rounding of the terms and its centroids' own float32 drift (a
+#: ``param_gap`` near 1e-7) move a margin by a few epsilon, so it may
+#: assign such a point to either (``benchlib.elref.Ties``)
+TIE_WIDTH = 16 * float(np.finfo(np.float32).eps)
 
 
-def local_step(P, cfg: dict, p: dict, x: np.ndarray, y: np.ndarray
-               ) -> dict:
+def _assign(P, x: np.ndarray, c: np.ndarray, pick=None) -> np.ndarray:
+    x2 = P.r(np.sum(x * x, axis=-1, keepdims=True))
+    xc = P.mm(x, np.swapaxes(c, -1, -2))
+    c2 = P.r(np.sum(c * c, axis=-1))[..., None, :]
+    d2 = P.r(x2 - 2.0 * xc + c2)
+    if pick is None:
+        return np.argmin(d2, axis=-1)
+    return pick(d2, x2 + 2.0 * np.abs(xc) + c2)
+
+
+def local_step(P, cfg: dict, p: dict, x: np.ndarray, y: np.ndarray,
+               pick=None) -> dict:
     """One minibatch Lloyd step on every edge: ``centers`` ``[E, K, D]``,
-    ``x`` ``[E, B, D]``."""
+    ``x`` ``[E, B, D]``; ``pick(d2, scale)``, where given, makes the
+    assignment in place of the argmin."""
     del y
     c = p["centers"]
-    a = _assign(P, x, c)                                  # [E, B]
+    a = _assign(P, x, c, pick)                            # [E, B]
     onehot = (a[..., None] == np.arange(c.shape[-2])).astype(x.dtype)
     counts = onehot.sum(axis=1)                           # [E, K]
     sums = P.mm(np.swapaxes(onehot, 1, 2), x)             # [E, K, D]
@@ -72,3 +85,14 @@ def step_flops(cfg: dict) -> float:
     """One local step on one edge: the distances x c^T and the cluster
     sums onehot^T x, each 2 B D K."""
     return 4.0 * cfg["batch"] * cfg["features"] * cfg["classes"]
+
+
+def n_params(cfg: dict) -> int:
+    """The centroids: K D."""
+    return cfg["features"] * cfg["classes"]
+
+
+def eval_flops(cfg: dict) -> float:
+    """The per-aggregation parameter-delta utility, 3 K D (the F1 is
+    host work)."""
+    return 3.0 * n_params(cfg)

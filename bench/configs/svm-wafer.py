@@ -51,3 +51,15 @@ def step_flops(cfg: dict) -> float:
     """One local step on one edge: the forward x w and the backward
     x^T dL/ds, each 2 B D C."""
     return 4.0 * cfg["batch"] * cfg["features"] * cfg["classes"]
+
+
+def n_params(cfg: dict) -> int:
+    """The weights and the biases: D C + C."""
+    return cfg["features"] * cfg["classes"] + cfg["classes"]
+
+
+def eval_flops(cfg: dict) -> float:
+    """The per-aggregation accuracy: x w over the held-out rows,
+    2 N_eval D C."""
+    n_eval = int(cfg["data"]["samples"] * cfg["data"]["test_frac"])
+    return 2.0 * n_eval * cfg["features"] * cfg["classes"]

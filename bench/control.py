@@ -10,10 +10,14 @@ precision on a TPU) where the configuration states float32 at ``high``.
 Each (precision, seed) runs the cell's harness once, at the cell's own
 sizes and load, in this one process (set-up is paid once per program),
 and prints one JSON line: the numbers compared, ``correct`` under the
-committed limits, and the run's counts.  The lower readings of the
-limits in ``bench/cells/`` come from ``config`` runs, the upper readings
-from ``default`` runs (``PERF.md`` lists both).  The benchmark's own runs
-never run this.  It needs the chip, as the harness does.
+committed limits, and the run's counts.  ``check-control`` puts the
+configuration's check reference in the program's place instead, a
+precision step lower, on the cell's ``sample`` runs
+(``benchlib.control``; for a ``device-f32`` check, on the chip).  The
+lower readings of the limits in ``bench/cells/`` come from ``config``
+runs, the upper readings from ``default`` runs (``PERF.md`` lists both).
+The benchmark's own runs never run this.  It needs the chip, as the
+harness does.
 """
 
 import argparse
@@ -23,6 +27,26 @@ import sys
 import time
 
 import run as bench_run
+
+
+#: the configuration's check reference in the program's place, a
+#: precision step lower (``benchlib.control``)
+REFERENCE = "check-control"
+
+
+def reference_control(workload: str, seed: int) -> dict:
+    """The check kind's control on ``limits["sample"]`` runs of the
+    cell's traffic: for ``device-f32`` the model's reference on the
+    chip with one-pass bfloat16 products, replayed at ``highest``."""
+    from benchlib import check, control
+    _, cell, cfg, ref, traffic, limits = bench_run.load_cell(workload)
+    bench_run.configure_jax(cfg)
+    bench_run.require_chips(cell["chips"])
+    nums = control.readings(cfg, ref, traffic, seed, int(limits["sample"]))
+    return {"workload": workload, "precision": REFERENCE, "seed": seed,
+            "check": cfg.get("check", "host-f64"),
+            "correct": check.verdict(nums, limits["limits"]),
+            "numbers": nums}
 
 
 def main(argv=None):
@@ -37,6 +61,12 @@ def main(argv=None):
               else {"config": {"matmul_precision": prec}})
         for seed in args.seeds:
             t0 = time.perf_counter()
+            if prec == REFERENCE:
+                print(json.dumps(dict(reference_control(args.workload,
+                                                        seed),
+                                      seconds=time.perf_counter() - t0)),
+                      flush=True)
+                continue
             out = io.StringIO()
             res = bench_run.run_cell(args.workload, seed, args.seconds,
                                      False, overrides=ov, out=out,

@@ -13,4 +13,4 @@ def make(n: int, d: int, k: int, seed: int):
     means = rng.normal(0.0, 0.35, size=(k, d))
     y = rng.choice(k, size=n, p=weights)
     x = means[y] + rng.normal(0.0, 1.0, size=(n, d))
-    return rng, x, y
+    return rng, x.astype(np.float32), y.astype(np.int32)

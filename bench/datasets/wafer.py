@@ -15,4 +15,4 @@ def make(n: int, d: int, n_classes: int, seed: int):
     x = means[y] + rng.normal(0.0, 1.0, size=(n, d)) * scales
     x = x @ (basis / np.sqrt(d))
     x = (x - x.mean(0)) / (x.std(0) + 1e-6)
-    return rng, x, y
+    return rng, x.astype(np.float32), y.astype(np.int32)

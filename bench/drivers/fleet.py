@@ -24,7 +24,8 @@ class Driver:
         self.cfg, self.traffic, self.fx = cfg, traffic, fx
         self.draws = Draws(seed, 2)
         self.server = FleetServer(n_slots=traffic["n_slots"],
-                                  rounds_per_wave=traffic["rounds_per_wave"])
+                                  rounds_per_wave=traffic["rounds_per_wave"],
+                                  mesh=fx["mesh"])
         self.done: Dict[str, float] = {}
         self.reports: Dict[str, Any] = {}
 

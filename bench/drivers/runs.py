@@ -1,7 +1,8 @@
 """Back-to-back single runs through ``ELSession.run_sync_ingraph`` or
 ``run_async_ingraph`` (the mix's ``mode``), each with a fresh seed and
-knobs drawn from the mix's ``knobs``.  The window runs from the first
-call's start to the end of the last call begun before ``seconds``."""
+knobs drawn from the mix's ``knobs``, on the cell's mesh (``None`` on
+one chip).  The window runs from the first call's start to the end of
+the last call begun before ``seconds``."""
 
 from __future__ import annotations
 
@@ -37,8 +38,8 @@ class Driver:
                                       **knobs)
         if self.mode == "sync":
             return self.session.run_sync_ingraph(
-                max_rounds=self.traffic["max_rounds"])
-        return self.session.run_async_ingraph()
+                max_rounds=self.traffic["max_rounds"], mesh=self.fx["mesh"])
+        return self.session.run_async_ingraph(mesh=self.fx["mesh"])
 
     def setup(self) -> None:
         self._call(self._plan(1)[0])

@@ -30,7 +30,8 @@ class Driver(Runs):
 
     def _call(self, knobs):
         self.session.cfg = run_config(self.fx, self.traffic, mode="sync")
-        return self.session.sweep(self._spec(knobs["seed"]))
+        return self.session.sweep(self._spec(knobs["seed"]),
+                                  mesh=self.fx["mesh"])
 
     def _counts(self, rep) -> Dict[str, int]:
         n = np.asarray(rep.out["n_rounds"])

@@ -2,8 +2,9 @@
 of the session is open: the idle that the program's spans leave
 unexplained.  A stage span is a host span named ``session.*`` other
 than the root ``session.call``, so idle inside a call but between its
-stages counts as unexplained, as does idle outside any call.  A program
-without the root span has no stage spans to read, and reads nothing."""
+stages counts as unexplained, as does idle outside any call.  Idle is
+each chip's own, summed over the cell's chips.  A program without the
+root span has no stage spans to read, and reads nothing."""
 
 from benchlib import trace
 
@@ -30,11 +31,14 @@ def read(ctx):
     host = ctx.trace["host"]
     if not any(name == ROOT for _, _, name in host):
         return None
-    busy = trace.union(ctx.trace["device"], ctx.lo, ctx.hi)
-    idle = ctx.hi - ctx.lo - sum(e - s for s, e in busy)
-    if idle <= 0:
-        return None
     stages = trace.union([h for h in host if h[2].startswith("session.")
                           and h[2] != ROOT], ctx.lo, ctx.hi)
-    covered = sum(e - s for s, e in stages) - overlap_ns(stages, busy)
+    staged = sum(e - s for s, e in stages)
+    idle = covered = 0
+    for device in ctx.trace["by_device"]:
+        busy = trace.union(device, ctx.lo, ctx.hi)
+        idle += ctx.hi - ctx.lo - sum(e - s for s, e in busy)
+        covered += staged - overlap_ns(stages, busy)
+    if idle <= 0:
+        return None
     return 100.0 * (idle - covered) / idle

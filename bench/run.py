@@ -5,18 +5,24 @@
 Everything the cell needs is found by name: the cell in
 ``BENCHMARK.json`` names its configuration (``bench/configs/<c>.json``
 with its plain reference ``<c>.py``) and its traffic
-(``bench/traffic/<t>.json``); ``bench/cells/<cell>.json`` holds the
-limits of its correctness check; each metric is read by
-``bench/metrics/<metric>.py``.  A cell, a mix or a metric is added by
-adding files.
+(``bench/traffic/<t>.json``, driven by ``bench/drivers/<kind>.py``);
+the configuration names how it becomes a program
+(``bench/programs/<program>.py``, ``classic`` by default), its data
+(``bench/datasets/<kind>.py``) and its check
+(``bench/checks/<check>.py``, ``host-f64`` by default);
+``bench/cells/<cell>.json`` holds the limits of its correctness check;
+each metric is read by ``bench/metrics/<metric>.py``.  A cell, a mix, a
+model kind, a check or a metric is added by adding files.
 
-One process, no children.  Set-up (import, data, program build and the
-warm-up of every shape the traffic uses, compiles included) runs from
-process start to the window; the window measures for ``--seconds``;
-then the program's state is freed and the float64 reference replays a
-sample of what the window produced, drawn from the seed.  ``--trace 1``
-runs the same window under the profiler and reports the per-layer
-metrics instead of the end-to-end ones.
+One process, no children.  A cell on more than one chip runs on a mesh
+over exactly its chips (``benchlib.program.mesh_for``).  Set-up (import,
+data, program build and the warm-up of every shape the traffic uses,
+compiles included) runs from process start to the window; the window
+measures for ``--seconds``; then the program's state is freed and the
+configuration's check replays a sample of what the window produced,
+drawn from the seed.  ``--trace 1`` runs the same window under the
+profiler and reports the per-layer metrics instead of the end-to-end
+ones.
 
 It runs on an accelerator only: without one, or with fewer chips than
 the cell asks for, it exits non-zero and prints no result.  JAX's
@@ -128,29 +134,23 @@ def configure_jax(cfg, cache=True):
 
 
 def check(cfg, ref, rows, limits, seed):
-    """Replay a sample of the window's runs with the float64 reference
-    and compare; returns ``(correct, numbers)``."""
+    """Replay a sample of the window's runs with the configuration's
+    check (``bench/checks/<kind>.py``) and compare; returns ``(correct,
+    numbers, n_checked, ties_followed)``, the last the checked runs
+    compared with a branch that assigns near ties the other way."""
     import numpy as np
 
-    from benchlib import check as chk, data, elref
-    from benchlib.prec import F64
-    edges, test = data.make(cfg)
-    wl = elref.Workload(cfg, ref, edges, test, F64)
+    from benchlib import check as chk
     rng = np.random.default_rng([seed, 9])
     n = min(len(rows), int(limits["sample"]))
     longest = int(np.argmax([r["record"]["n"] for r in rows]))
     pick = [longest] + [int(i) for i in rng.permutation(len(rows))
                         if i != longest][:n - 1]
-    results = []
-    for i in pick:
-        run = dict(rows[i]["run"], init=rows[i]["init"])
-        sim = (elref.simulate_sync if run["mode"] == "sync"
-               else elref.simulate_async)
-        replay = sim(wl, run, forced=rows[i]["record"])
-        results.append(chk.compare(rows[i]["record"], replay, run["budget"],
-                                   cfg["n_edges"]))
-    numbers = chk.worst(results)
-    return chk.verdict(numbers, limits["limits"]), numbers, len(pick)
+    wl = chk.kind(cfg).workload(cfg, ref)
+    got = [chk.replay_numbers(cfg, wl, rows[i]) for i in pick]
+    numbers = chk.worst(got)
+    return (chk.verdict(numbers, limits["limits"]), numbers, len(pick),
+            sum(g["ties_followed"] > 0 for g in got))
 
 
 def run_cell(workload, seed, seconds, trace, *, root=ROOT,
@@ -169,6 +169,7 @@ def run_cell(workload, seed, seconds, trace, *, root=ROOT,
     configure_jax(cfg, cache)
     import jax
     devs = require_chips(cell["chips"]) if require_device else jax.devices()
+    chips = devs[:cell["chips"]]
     from benchlib import drive, program
     from benchlib import trace as btrace
     from repro.obs import trace as obs_trace
@@ -176,7 +177,7 @@ def run_cell(workload, seed, seconds, trace, *, root=ROOT,
     clock = CompileClock()
     init_seed = int(drive.Draws(seed, 0).seeds(1)[0])
     init = ref.init(cfg, init_seed)
-    fx = program.build(cfg, init)
+    fx = program.build(cfg, init, program.mesh_for(cfg, cell["chips"]))
     drv = drive.make(cfg, traffic, fx, seed)
     drv.setup()
     setup_s = time.perf_counter() - T_PROCESS
@@ -195,8 +196,9 @@ def run_cell(workload, seed, seconds, trace, *, root=ROOT,
     finally:
         obs_trace.use_tracer(prev_tracer)
     compiles_in_window = clock.compiles - compiles0
-    stats = devs[0].memory_stats() or {}
-    memory_peak = stats.get("peak_bytes_in_use")
+    per_chip = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                for d in chips]
+    memory_peak = max((b for b in per_chip if b is not None), default=None)
 
     spans = tracer.events()
     calls = [{k: v for k, v in c.items() if k != "report"}
@@ -209,7 +211,8 @@ def run_cell(workload, seed, seconds, trace, *, root=ROOT,
     attempted = len(lat) if lat is not None else len(calls)
     failed = drv.missing() if lat is not None else 0
 
-    correct, numbers, n_checked = check(cfg, ref, rows, limits, seed)
+    correct, numbers, n_checked, ties = check(cfg, ref, rows, limits,
+                                              seed)
 
     ctx = types.SimpleNamespace(
         cfg=cfg, ref=ref, traffic=traffic, cell=cell, seconds=seconds,
@@ -220,7 +223,7 @@ def run_cell(workload, seed, seconds, trace, *, root=ROOT,
             "attempted": attempted, "aggregations": win["aggs"],
             "window_s": ctx.window_s, "setup_s": setup_s,
             "compiles_in_window": compiles_in_window,
-            "checked_runs": n_checked}
+            "checked_runs": n_checked, "ties_followed": ties}
     if lat is not None:
         info["generator_late_ms_max"] = max(drv.lateness, default=0.0) * 1e3
         info["generator_late_ms_mean"] = (float(np.mean(drv.lateness)) * 1e3
@@ -229,7 +232,8 @@ def run_cell(workload, seed, seconds, trace, *, root=ROOT,
     print(json.dumps(info), file=out, flush=True)
 
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": cell["chips"], "memory_peak_bytes": memory_peak}
+              "count": cell["chips"], "memory_peak_bytes": memory_peak,
+              "memory_peak_bytes_per_chip": per_chip}
     result = {"correct": bool(correct), "attempted": attempted,
               "failed": failed}
     if trace:
@@ -240,14 +244,15 @@ def run_cell(workload, seed, seconds, trace, *, root=ROOT,
         lo, hi = win_ev[0][0], win_ev[0][1]
         ctx.trace, ctx.lo, ctx.hi = tr, lo, hi
         ctx.peak = peak_for(devs[0].device_kind) if require_device else None
-        busy = btrace.busy_ns(tr["device"], lo, hi) / 1e9
-        device["busy_s"] = busy
+        device["busy_s"] = btrace.busy_ns_per_device(
+            tr["by_device"], lo, hi) / 1e9
         device["window_s"] = (hi - lo) / 1e9
         result["breakdown"] = {
-            "device_ops": [list(x) for x in
-                           btrace.op_totals(tr["device"], lo, hi)[:10]],
-            "idle_gaps": [list(x) for x in btrace.idle_gaps(
-                tr["device"], tr["host"], lo, hi, HOST_SPANS)[:10]]}
+            "device_ops": [list(x) for x in btrace.mean_totals(
+                [btrace.op_totals(d, lo, hi) for d in tr["by_device"]])[:10]],
+            "idle_gaps": [list(x) for x in btrace.mean_totals(
+                [btrace.idle_gaps(d, tr["host"], lo, hi, HOST_SPANS)
+                 for d in tr["by_device"]])[:10]]}
     metrics = {}
     for m in metrics_for(bench, workload, trace):
         mod = load_named("metrics", m["name"], os.path.join(root, "bench"))

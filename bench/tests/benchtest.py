@@ -83,9 +83,55 @@ def checkout(cell: str) -> str:
 _TEST_ROOTS: dict = {}
 
 
+def variant(cell: str, name: str, chips: int = None, config: str = None,
+            config_entries: dict = None, reference: str = None) -> str:
+    """A checkout that adds, by files alone, the cell ``name``: ``cell``
+    on ``chips`` chips, or of the configuration ``config``, a copy of
+    ``cell``'s with ``config_entries`` and the plain reference at
+    ``reference`` (a path under ``bench/tests/data``).  Made once per
+    process."""
+    key = (cell, name, chips, config)
+    if key in _TEST_ROOTS:
+        return _TEST_ROOTS[key]
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    root = tempfile.mkdtemp(prefix="bench-variant-")
+    atexit.register(shutil.rmtree, root, True)
+    for d in ("configs", "traffic", "cells", "metrics"):
+        shutil.copytree(os.path.join(BENCH, d), os.path.join(root, "bench", d))
+    w = dict(next(x for x in bench["workloads"] if x["name"] == cell),
+             name=name)
+    if chips is not None:
+        w["chips"] = chips
+    if config is not None:
+        conf = next(c for c in bench["configs"] if c["name"] == w["config"])
+        with open(os.path.join(ROOT, conf["file"])) as f:
+            body = dict(json.load(f), name=config, **(config_entries or {}))
+        path = os.path.join(root, "bench", "configs", config)
+        with open(path + ".json", "w") as f:
+            json.dump(body, f)
+        shutil.copy(os.path.join(BENCH, "tests", "data", reference),
+                    path + ".py")
+        bench["configs"].append(dict(conf, name=config,
+                                     file=f"bench/configs/{config}.json"))
+        w["config"] = config
+    bench["workloads"].append(w)
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if cell in m.get("workloads", ()):
+            m["workloads"].append(name)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    shutil.copy(os.path.join(BENCH, "cells", cell + ".json"),
+                os.path.join(root, "bench", "cells", name + ".json"))
+    _TEST_ROOTS[key] = root
+    return root
+
+
 def run_small(cell: str, seed: int = 2**31 + 77, seconds: float = 0.5,
-              trace: bool = False, root: str = None):
-    """One CPU run of ``cell`` at small size; returns its result dict."""
+              trace: bool = False, root: str = None, extra: dict = None):
+    """One CPU run of ``cell`` at small size; returns its result dict.
+    ``extra`` adds overrides: entries of the configuration, the traffic
+    or the limits."""
     root = root or checkout(cell)
     ov = small(cell)
     if cell.endswith("run-sync") or cell.endswith("run-async"):
@@ -94,6 +140,8 @@ def run_small(cell: str, seed: int = 2**31 + 77, seconds: float = 0.5,
         ov["traffic"].pop("rate_per_s")
     else:
         ov["traffic"] = {"rate_per_s": 40.0}
+    for part, entries in (extra or {}).items():
+        ov.setdefault(part, {}).update(entries)
     return harness().run_cell(cell, seed, seconds, trace,
                               require_device=False, cache=False,
                               overrides=ov, root=root, out=io.StringIO(),

@@ -38,7 +38,7 @@ def test_svm_step_and_round_by_hand():
 
 def test_kmeans_step_and_kernel_launch_by_hand():
     assert counts.step_flops(KM, KM_REF) == 4 * 128 * 64 * 3
-    assert counts.eval_flops(KM) == 3 * 3 * 64
+    assert counts.eval_flops(KM, KM_REF) == 3 * 3 * 64
     launch = counts.kmeans_assign_launch(rows=128, d=64, k=3)
     assert launch["flops"] == 2 * 128 * 3 * 64 + 2 * 128 * 64 + 5 * 128 * 3
     assert launch["bytes"] == 4 * 128 * 64 + 4 * 3 * 64 + 8 * 128

@@ -50,7 +50,7 @@ def test_a_cell_mix_and_metric_are_added_by_adding_files(tmp_path,
     root = tmp_path / "checkout"
     (root / "bench").mkdir(parents=True)
     for d in ("configs", "traffic", "cells", "metrics", "drivers",
-              "datasets"):
+              "datasets", "programs", "checks"):
         shutil.copytree(os.path.join(BENCH, d), root / "bench" / d)
     monkeypatch.setattr(benchlib, "BENCH", str(root / "bench"))
     b = _bench()
@@ -92,6 +92,50 @@ def test_a_cell_mix_and_metric_are_added_by_adding_files(tmp_path,
         json.loads((root / "BENCHMARK.json").read_text()),
         "svm-wafer.run-sync-mixed", True)]
     assert "calls_in_window" in got
+
+    # a new kind of program and a new kind of check (each a file that
+    # notes its use), a configuration that names both, and a cell of it
+    # on four chips: the harness asks for a four-chip mesh for it
+    seen = []
+    monkeypatch.setattr(benchlib, "seen", seen, raising=False)
+    (root / "bench" / "programs" / "classic-noted.py").write_text(
+        "import benchlib\n\n"
+        "def build(cfg, init, mesh):\n"
+        "    benchlib.seen.append(('program', cfg['name']))\n"
+        "    return benchlib.load_named('programs', 'classic').build(\n"
+        "        cfg, init, mesh)\n")
+    (root / "bench" / "checks" / "host-f64-noted.py").write_text(
+        "import benchlib\n\n"
+        "def workload(cfg, ref, control=False):\n"
+        "    benchlib.seen.append(('check', cfg['name']))\n"
+        "    return benchlib.load_named('checks', 'host-f64').workload(\n"
+        "        cfg, ref, control)\n")
+    with open(os.path.join(BENCH, "configs", "svm-wafer.json")) as f:
+        conf = json.load(f)
+    conf.update(name="svm-noted", program="classic-noted",
+                check="host-f64-noted", param_gap_of="update")
+    (root / "bench" / "configs" / "svm-noted.json").write_text(
+        json.dumps(conf))
+    shutil.copy(os.path.join(BENCH, "configs", "svm-wafer.py"),
+                root / "bench" / "configs" / "svm-noted.py")
+    shutil.copy(os.path.join(BENCH, "cells", "svm-wafer.run-sync.json"),
+                root / "bench" / "cells" / "svm-noted.run-sync-4.json")
+    b["configs"].append(dict(b["configs"][0], name="svm-noted",
+                             file="bench/configs/svm-noted.json"))
+    b["workloads"].append({"name": "svm-noted.run-sync-4",
+                           "config": "svm-noted", "traffic": "run-sync",
+                           "chips": 4, "why": "test"})
+    b["end_to_end"][0]["workloads"].append("svm-noted.run-sync-4")
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    from benchlib import program
+    monkeypatch.setattr(program, "mesh_for",
+                        lambda cfg, n: seen.append(("mesh", n)))
+    _, cell, cfg, _, _, _ = h.load_cell("svm-noted.run-sync-4", str(root))
+    assert cell["chips"] == 4 and cfg["program"] == "classic-noted"
+    res = run_small("svm-noted.run-sync-4", root=str(root))
+    assert res["correct"] and res["device"]["count"] == 4
+    assert seen == [("mesh", 4), ("program", "svm-noted"),
+                    ("check", "svm-noted")]
 
 
 def test_a_run_field_the_reference_does_not_model_is_refused():
@@ -144,3 +188,71 @@ def test_draws_are_stratified_and_seeded():
         {"process": "bursty", "on_s": 2.0, "off_s": 3.0}, 10.0, 400))
     assert np.all(np.mod(t, 5.0) <= 2.0 + 1e-9)
     assert abs(400 / t[-1] - 10.0) < 1.5
+
+
+def test_a_longer_plan_keeps_its_first_calls():
+    """Raising a mix's ``plan_calls`` leaves every call the old plan
+    made as it was: the window's first calls and their seeds stay."""
+    from benchlib.drive import Draws
+    for mix, before in (("run-sync", 4000), ("run-async", 1000)):
+        with open(os.path.join(BENCH, "traffic", mix + ".json")) as f:
+            traffic = json.load(f)
+        assert traffic["plan_calls"] >= 16 * before
+
+        def plan(n):
+            d = Draws(2**31 + 1234, 1)          # as the runs driver draws
+            d.plan(traffic["knobs"], 1)         # the warm-up call
+            return d.plan(traffic["knobs"], n)
+
+        assert plan(traffic["plan_calls"])[:before] == plan(before)
+
+
+def test_busy_per_device_averages_the_chips():
+    from benchlib import trace
+    one = [(0, 10, "a"), (5, 20, "b")]
+    other = [(30, 40, "c")]
+    assert trace.busy_ns_per_device([one], 0, 60) == 20
+    assert trace.busy_ns_per_device([one, other], 0, 60) == 15
+    assert trace.busy_ns_per_device([], 0, 60) == 0
+
+
+def test_device_readings_take_each_chip_apart():
+    """Two chips that take turns, each busy half of the window: no instant
+    of the window is idle on both, but each chip idles half of it, and
+    every busy or idle reading says so, as ``busy_s`` does."""
+    import types
+
+    import pytest
+    from benchlib import load_named, trace
+    a, b = [(0, 30, "fusion.1")], [(30, 60, "fusion.2")]
+    host = [(0, 60, "session.call"), (0, 15, "session.dispatch"),
+            (45, 60, "session.records")]
+    ctx = types.SimpleNamespace(
+        trace={"device": a + b, "by_device": [a, b], "host": host},
+        lo=0, hi=60, aggs=3)
+
+    def read(name):
+        return load_named("metrics", name).read(ctx)
+
+    assert read("device_idle_share") == pytest.approx(50.0)
+    assert read("program.device_us_per_agg") == pytest.approx(30e-3 / 3)
+    # idle [30,60) on chip a and [0,30) on chip b: the stages cover
+    # [45,60) of the one and [0,15) of the other, 30 of 60 ns
+    assert read("session.unspanned_idle_share") == pytest.approx(50.0)
+    assert trace.mean_totals([trace.op_totals(d, 0, 60) for d in (a, b)]) \
+        == [("fusion", 30e-9)]
+    # each chip's one gap is named by the span open at its midpoint
+    gaps = trace.mean_totals([trace.idle_gaps(d, host, 0, 60,
+                                              ("session.dispatch",
+                                               "session.records"))
+                              for d in (a, b)])
+    assert dict(gaps) == pytest.approx({"session.records": 15e-9,
+                                        "session.dispatch": 15e-9})
+
+
+def test_mesh_for_one_chip_is_none_and_axes_must_cover_the_chips():
+    import pytest
+    from benchlib import program
+    assert program.mesh_for({"name": "c"}, 1) is None
+    with pytest.raises(ValueError, match="does not cover"):
+        program.mesh_for({"name": "c", "mesh": {"data": 2, "model": 1}}, 4)

@@ -19,7 +19,8 @@ HOST = [(0, 60, "bench.call"), (18, 58, "session.call"),
 
 
 def _ctx(host, device=DEVICE):
-    return types.SimpleNamespace(trace={"device": device, "host": host},
+    return types.SimpleNamespace(trace={"device": device,
+                                        "by_device": [device], "host": host},
                                  lo=0, hi=60)
 
 
