@@ -93,6 +93,12 @@ class ModelConfig:
     tie_embeddings: bool = False
     act_fn: str = "silu"                 # silu (SwiGLU) | gelu (GeGLU)
     sliding_window: int = 0              # 0 = full causal attention
+    rope: bool = True                    # False: no positional encoding (NoPE)
+    # muP multipliers (Granite-4.0-H); the defaults leave a model as it is
+    embedding_multiplier: float = 1.0    # token embeddings times this
+    residual_multiplier: float = 1.0     # each mixer / FFN output times this
+    attention_multiplier: float = 0.0    # attention scale; 0 -> head_dim**-0.5
+    logits_scaling: float = 1.0          # logits divided by this
     # Layer pattern. Empty -> all layers are ``attn``. Otherwise a pattern of
     # ATTN/MAMBA strings which is tiled across n_layers (Jamba-style).
     layer_pattern: Tuple[str, ...] = ()
@@ -120,6 +126,12 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // max(self.n_heads, 1)
+
+    @property
+    def attn_scale(self) -> float:
+        """The attention logits' scale: ``attention_multiplier`` where
+        set, else ``1/sqrt(head_dim)``."""
+        return self.attention_multiplier or self.resolved_head_dim ** -0.5
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Per-layer kind list of length n_layers."""
@@ -313,6 +325,7 @@ ARCH_IDS: Tuple[str, ...] = (
     "deepseek-coder-33b",
     "olmoe-1b-7b",
     "qwen3-1.7b",
+    "granite-4.0-h-micro",
 )
 
 # Paper-native workloads (selectable just like archs).

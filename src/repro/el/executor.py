@@ -7,8 +7,9 @@ and ``LMExecutor`` for language models) satisfy it structurally — no
 inheritance needed; third-party executors only have to match the shapes.
 
 ``InGraphExecutor`` is the narrower contract the compiled sync fast path
-needs (raw per-edge arrays + a jittable model) — only ``ClassicExecutor``
-satisfies it today.
+needs (raw per-edge arrays + a jittable model) — ``ClassicExecutor``
+satisfies it, and ``TokenLMExecutor`` (a language model on per-edge
+token rows) for the single sync run.
 """
 
 from __future__ import annotations

@@ -51,7 +51,14 @@ with the async event-horizon program in ``repro.el.events``:
   utility          ``eval_gain`` (needs a jittable metric) and
                    ``param_delta``
   executor         ``InGraphExecutor`` shape — raw per-edge arrays + a
-                   jittable ``model.local_step`` (``ClassicExecutor``)
+                   jittable ``model.local_step`` (``ClassicExecutor``);
+                   a language model on per-edge token rows
+                   (``TokenLMExecutor``) as a single sync run
+                   (``run_sync_ingraph``) without a scenario: its round
+                   runs each edge's steps unmasked and aggregates
+                   without replicating the ``[E, params]`` stack
+                   (``make_token_round``); async, sweeps and the fleet
+                   refuse it
   ==============  =======================================================
 
 Everything else stays on the host paths (``ELSession.run_sync`` /
@@ -121,12 +128,15 @@ def support_matrix() -> str:
         "waves)\n"
         f"  utility     {_INGRAPH_UTILITIES}\n"
         "  executor    InGraphExecutor shape (raw per-edge arrays + a "
-        "jittable model.local_step, e.g. ClassicExecutor)")
+        "jittable model.local_step, e.g. ClassicExecutor); a language "
+        "model on token rows (TokenLMExecutor) only as a single sync run "
+        "(run_sync_ingraph) with scenario=None — async, sweep and fleet "
+        "refuse it")
 
 
 def check_ingraph_support(cfg: OL4ELConfig, executor: Any = None, *,
-                          caller: str = "the in-graph fast path"
-                          ) -> None:
+                          caller: str = "the in-graph fast path",
+                          single_run: bool = False) -> None:
     """Validate a config/executor combination against the supported matrix.
 
     Raises ``ValueError`` naming the unsupported (policy, cost_model,
@@ -137,7 +147,9 @@ def check_ingraph_support(cfg: OL4ELConfig, executor: Any = None, *,
     (``Policy.ingraph_modes``): ``ol4el`` compiles in both modes — one
     shared bandit in sync, per-edge bandits in async — and the
     task-allocation baselines compile through the sync scenario policy
-    switch (``repro.el.scenarios.baselines``).
+    switch (``repro.el.scenarios.baselines``).  An executor of token
+    rows (a language model) is admitted only for a ``single_run`` in
+    sync mode without a scenario.
     """
     from repro.el import policies as el_policies
     from repro.el.scenarios.spec import ScenarioSpec
@@ -190,6 +202,14 @@ def check_ingraph_support(cfg: OL4ELConfig, executor: Any = None, *,
             f"{caller} does not support utility={cfg.utility!r} with "
             f"{_combo(cfg, executor)}: in-graph utilities are "
             f"{_INGRAPH_UTILITIES}\n" + support_matrix())
+    if executor is not None and is_token_data(
+            getattr(executor, "edge_data", None)) and not (
+            single_run and cfg.mode == "sync" and scn is None):
+        raise ValueError(
+            f"{caller} does not support {_combo(cfg, executor)} in "
+            f"mode={cfg.mode!r}: a language model on token rows compiles "
+            "only as a single sync run (run_sync_ingraph) with "
+            "scenario=None\n" + support_matrix())
     if executor is not None:
         missing = [a for a in INGRAPH_EXECUTOR_ATTRS
                    if not hasattr(executor, a)]
@@ -275,8 +295,12 @@ def _pad_edge_data(edge_data: List[Dict[str, np.ndarray]]
 
 def default_metric_fn(model, eval_set, metric_name: str
                       ) -> Optional[Callable[[Params], jax.Array]]:
-    """A jittable eval metric when the model supports one (SVM accuracy);
-    None means the in-graph path must run with a params-only utility."""
+    """A jittable eval metric when the model supports one (SVM accuracy,
+    a language model's held-out negative loss); None means the in-graph
+    path must run with a params-only utility."""
+    if metric_name == "neg_loss" and hasattr(model, "token_loss"):
+        toks = jnp.asarray(eval_set["tokens"], jnp.int32)
+        return lambda params: -model.token_loss(params, toks)
     if metric_name == "accuracy" and hasattr(model, "scores"):
         xe = jnp.asarray(eval_set["x"], jnp.float32)
         ye = jnp.asarray(eval_set["y"], jnp.int32)
@@ -381,6 +405,145 @@ def _edge_stack_constraints(mesh, n_edges: int
     return constrain, gather
 
 
+def is_token_data(edge_data) -> bool:
+    """Whether per-edge datasets are token rows (``{"tokens": [N_e,
+    S+1]}``, a language model's edges) rather than ``x``/``y`` rows."""
+    return bool(edge_data) and "tokens" in edge_data[0]
+
+
+def _pad_token_data(edge_data: List[Dict[str, np.ndarray]]
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """Stack per-edge token rows ``[E, Nmax, S+1]`` (int32, wraparound
+    padding as ``_pad_edge_data``) and the per-edge counts ``[E]``."""
+    n = np.array([len(d["tokens"]) for d in edge_data], np.int32)
+    n_max = int(n.max())
+    toks = np.stack([np.resize(np.asarray(d["tokens"], np.int32),
+                               (n_max,) + np.shape(d["tokens"])[1:])
+                     for d in edge_data])
+    return jnp.asarray(toks), jnp.asarray(n)
+
+
+def make_token_block(model, batch: int, lr: float) -> Callable:
+    """``local_block(params, toks, n, interval, key)`` — ``interval``
+    local SGD steps (``model.local_step``) on one edge's token rows
+    ``toks`` ``[N, S+1]`` of which the first ``n`` are its own.  Each
+    step draws ``batch`` rows as ``make_local_block`` draws minibatch
+    rows (row = floor(u * n), ``u`` from ``fold_in(key, step)``), so the
+    streams are the classic block's; steps past ``interval`` are not
+    run (a loop of ``interval`` trips, not a masked scan: a language
+    model's step is too dear to compute and throw away)."""
+
+    def local_block(params: Params, toks: jax.Array, n: jax.Array,
+                    interval: jax.Array, key: jax.Array) -> Params:
+        def step(s, p):
+            u = jax.random.uniform(jax.random.fold_in(key, s), (batch,))
+            idx = (u * n.astype(jnp.float32)).astype(jnp.int32)
+            p2, _ = model.local_step(p, {"tokens": toks[idx]}, lr)
+            return p2
+
+        return lax.fori_loop(0, interval, step, params)
+
+    return local_block
+
+
+def fixed_order_mean(stack: Params, w: jax.Array) -> Params:
+    """``sum_e w[e] stack[e]`` leaf by leaf, edge 0 first, one edge at a
+    time: the same sum in the same order on whatever slice of a leaf it
+    is given, which is what makes the sharded exchange below equal the
+    unsharded mean bit for bit."""
+    def leaf_mean(x):
+        acc = x[0] * w[0]
+        for e in range(1, x.shape[0]):
+            acc = acc + x[e] * w[e]
+        return acc
+
+    return jax.tree.map(leaf_mean, stack)
+
+
+def _exchange_mean(leaf: jax.Array, w: jax.Array, axes, n_shards: int
+                   ) -> jax.Array:
+    """Inside ``shard_map``: the weighted mean over every edge of one
+    leaf whose local stack ``[E_local, ...]`` holds this chip's edges.
+    An all-to-all gives each chip a 1/n_shards slice of every edge's
+    leaf (a reduce-scatter that keeps the sum's order), the chip sums
+    its slice in ``fixed_order_mean``'s order, and an all-gather of the
+    sums rebuilds the leaf: no chip ever holds the ``[E, ...]`` stack."""
+    shape = leaf.shape[1:]
+    dims = [d for d in range(len(shape)) if shape[d] % n_shards == 0]
+    d = max(dims, key=lambda i: shape[i]) if dims else len(shape) - 1
+    pad = -shape[d] % n_shards
+    if pad:                    # no dim divides: pad one, slice it back
+        widths = [(0, 0)] * leaf.ndim
+        widths[1 + d] = (0, pad)
+        leaf = jnp.pad(leaf, widths)
+    part = lax.all_to_all(leaf, axes, 1 + d, 0, tiled=True)
+    full = lax.all_gather(fixed_order_mean(part, w), axes, axis=d,
+                          tiled=True)
+    if pad:
+        full = lax.slice_in_dim(full, 0, full.shape[d] - pad, axis=d)
+    return full.reshape(shape)
+
+
+def make_token_round(model, edge_data, n_edges: int, *, lr: float,
+                     batch: int, w_agg: jax.Array, mesh=None) -> Callable:
+    """``edge_round(params, interval, keys)`` for a language model's
+    sync round: every edge runs ``interval`` local steps from the global
+    ``params`` on its token rows, and the cloud takes the n_e-weighted
+    mean (``fixed_order_mean``).  Without a mesh (or where the edges do
+    not tile its edge axes) the edges run one after another and the
+    mean is taken whole (on a mesh, by every chip alike).  With edges
+    that tile the mesh's edge axes, the round is a ``shard_map`` over
+    them: each chip runs its own edges, one at a time, on its shard of
+    the token rows, and the mean is ``_exchange_mean``'s, so a chip
+    holds the global copy, its edge's copy and one parameter-sized
+    accumulator, never the ``[E, params]`` stack (the classic round's
+    all-gather would put ``E`` copies on every chip)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.sharding import el_edge_dim_axes
+    toks, n_per_edge = _pad_token_data(edge_data)
+    local_block = make_token_block(model, batch, lr)
+    axes = None
+    if mesh is not None:
+        axes = el_edge_dim_axes(mesh.axis_names, dict(
+            zip(mesh.axis_names, np.shape(mesh.devices))), n_edges)
+        (toks,) = _shard_edge_data(mesh, n_edges, toks)
+
+    def run_edges(params, toks, n, keys, interval):
+        """The ``[E_here, ...]`` stack of the edges whose rows ``toks``
+        holds, run one after another."""
+        return lax.map(lambda a: local_block(params, toks[a[0]], a[1],
+                                             interval, a[2]),
+                       (jnp.arange(toks.shape[0]), n, keys))
+
+    def whole_mean(params, toks, n, keys, interval):
+        return fixed_order_mean(run_edges(params, toks, n, keys, interval),
+                                w_agg)
+
+    if mesh is None:
+        return lambda params, interval, keys: whole_mean(
+            params, toks, n_per_edge, keys, interval)
+    if axes is None:
+        # every chip runs every edge: the model's kernels cannot be
+        # partitioned, so even a replicated round is a shard_map
+        body, edge = whole_mean, P()
+    else:
+        sizes = dict(zip(mesh.axis_names, np.shape(mesh.devices)))
+        n_shards = int(np.prod([sizes[a] for a in axes]))
+        edge = P(axes)
+
+        def body(params, toks, n, keys, interval):
+            return jax.tree.map(
+                lambda leaf: _exchange_mean(leaf, w_agg, axes, n_shards),
+                run_edges(params, toks, n, keys, interval))
+
+    sharded = jax.shard_map(body, mesh=mesh,
+                            in_specs=(P(), edge, edge, edge, P()),
+                            out_specs=P(), check_vma=False)
+    return lambda params, interval, keys: sharded(
+        params, toks, n_per_edge, keys, interval)
+
+
 @dataclasses.dataclass(frozen=True)
 class ELCell:
     """One EL run's compiled loop, split into composable pieces.
@@ -439,14 +602,22 @@ def make_sync_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
 
     n_edges, k = cfg.n_edges, cfg.max_interval
 
-    xs, ys, n_per_edge = _pad_edge_data(edge_data)
-    constrain_edge_stack, gather_edge_stack = _edge_stack_constraints(
-        mesh, n_edges)
-    if mesh is not None:
-        xs, ys = _shard_edge_data(mesh, n_edges, xs, ys)
     w_agg = (np.ones(n_edges) if n_samples is None
              else np.asarray(n_samples, np.float64))
     w_agg = jnp.asarray(w_agg / w_agg.sum(), jnp.float32)
+    tokens = is_token_data(edge_data)
+    if tokens:
+        token_round = make_token_round(model, edge_data, n_edges, lr=lr,
+                                       batch=batch, w_agg=w_agg, mesh=mesh)
+        seq_len = int(np.shape(edge_data[0]["tokens"])[1]) - 1
+    else:
+        xs, ys, n_per_edge = _pad_edge_data(edge_data)
+        constrain_edge_stack, gather_edge_stack = _edge_stack_constraints(
+            mesh, n_edges)
+        if mesh is not None:
+            xs, ys = _shard_edge_data(mesh, n_edges, xs, ys)
+        local_block = make_local_block(model, xs, ys, n_per_edge, batch,
+                                       lr, k, drift=scn is not None)
 
     if metric_fn is None:
         metric_fn = default_metric_fn(model, eval_set, metric_name)
@@ -454,15 +625,37 @@ def make_sync_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
         raise ValueError(
             "utility='eval_gain' needs a jittable metric; pass metric_fn= "
             "or use utility='param_delta'")
-
-    local_block = make_local_block(model, xs, ys, n_per_edge, batch, lr, k,
-                                   drift=scn is not None)
+    if tokens and mesh is not None and metric_fn is not None:
+        # a language model's metric runs its kernels, which XLA cannot
+        # partition: every chip computes it whole on its global copy
+        from jax.sharding import PartitionSpec as P
+        metric_fn = jax.shard_map(metric_fn, mesh=mesh, in_specs=P(),
+                                  out_specs=P(), check_vma=False)
 
     def weighted_mean(trees: Params) -> Params:
         return jax.tree.map(
             lambda leaf: jnp.einsum(
                 "e...,e->...", leaf.astype(jnp.float32), w_agg
             ).astype(leaf.dtype), trees)
+
+    def classic_round(params: Params, interval: jax.Array,
+                      keys: jax.Array) -> Params:
+        edge_ids = jnp.arange(n_edges)
+        bcast = jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (n_edges,) + x.shape), params)
+        # data plane: the per-edge param stack (and with it the
+        # vmapped local blocks) shards over the mesh's edge axes ...
+        bcast = constrain_edge_stack(bcast)
+        edge_params = jax.vmap(local_block, in_axes=(0, 0, None, 0))(
+            bcast, edge_ids, interval, keys)
+        # ... and is all-gathered BEFORE the aggregation so the
+        # einsum reduces replicated, in the unsharded program's
+        # exact accumulation order (bit-identity; a psum over the
+        # sharded edge dim would be an ulp off)
+        edge_params = gather_edge_stack(edge_params)
+        return weighted_mean(edge_params)
+
+    edge_round = token_round if tokens else classic_round
 
     def init(init_params: Params, rng: jax.Array,
              knobs: Dict[str, jax.Array]) -> Dict[str, Any]:
@@ -518,19 +711,7 @@ def make_sync_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
 
         edge_ids = jnp.arange(n_edges)
         keys = jax.vmap(lambda e: jax.random.fold_in(k_data, e))(edge_ids)
-        bcast = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (n_edges,) + x.shape), params)
-        # data plane: the per-edge param stack (and with it the
-        # vmapped local blocks) shards over the mesh's edge axes ...
-        bcast = constrain_edge_stack(bcast)
-        edge_params = jax.vmap(local_block, in_axes=(0, 0, None, 0))(
-            bcast, edge_ids, interval, keys)
-        # ... and is all-gathered BEFORE the aggregation so the
-        # einsum reduces replicated, in the unsharded program's
-        # exact accumulation order (bit-identity; a psum over the
-        # sharded edge dim would be an ulp off)
-        edge_params = gather_edge_stack(edge_params)
-        new_params = weighted_mean(edge_params)
+        new_params = edge_round(params, interval, keys)
 
         # straggler semantics: every edge's clock advances by the
         # slowest edge's round time (matches CloudCoordinator.charge
@@ -690,6 +871,13 @@ def make_sync_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
         out["budgets_left"] = knobs["budget"] - carry["consumed"]
         out["arm_pulls"] = carry["bstate"]["counts"]
         out["wall_time"] = carry["wall"]
+        if tokens:
+            # the local steps and tokens each edge trained (every edge
+            # runs each round's interval in sync)
+            steps = jnp.full((n_edges,), jnp.sum(carry["hist"]["interval"]),
+                             jnp.int32)
+            out["edge_steps"] = steps
+            out["edge_tokens"] = steps * (batch * seq_len)
         if spec is not None:
             out["telemetry"] = finalize_telemetry(carry["telem"],
                                                   carry["t"], spec)

@@ -42,6 +42,12 @@ Params = Any
 RoundCallback = Callable[[RoundRecord], None]
 
 
+def _on_host(params: Params) -> bool:
+    """Whether every leaf of ``params`` is a host (numpy) array."""
+    leaves = jax.tree.leaves(params)
+    return bool(leaves) and all(isinstance(x, np.ndarray) for x in leaves)
+
+
 def _session_call(mode: str):
     """Wrap a compiled entry point in its root ``session.call`` span
     (``repro.obs.trace``): the stage spans the call opens inside —
@@ -394,7 +400,8 @@ class ELSession:
     def _profile_program(self, key: tuple, program: Any, params: Params,
                          example_args: tuple, *, mode: str, mesh,
                          donate: bool, profile: bool, contract,
-                         scenario: bool = False) -> Any:
+                         scenario: bool = False,
+                         exchange: bool = False) -> Any:
         """The dispatch-time half of the performance observatory
         (``repro.obs.prof``): lazily extract a ``ProgramProfile`` for
         the cached program (once per cache entry — the AOT compile
@@ -428,7 +435,7 @@ class ELSession:
             if c is True:
                 c = obs_prof.default_contract(
                     mesh=mesh, donated=donate, mode=mode,
-                    scenario=scenario,
+                    scenario=scenario, exchange=exchange,
                     param_bytes=obs_prof.param_tree_bytes(params))
             c.enforce(prof)
         return prof
@@ -456,7 +463,8 @@ class ELSession:
 
     def _ingraph_cfg(self, caller: str,
                      mode: Optional[str] = None) -> OL4ELConfig:
-        """The effective (mode-coerced, support-checked) fast-path config."""
+        """The effective (mode-coerced, support-checked) fast-path config;
+        a ``mode`` names a single run (``run_*_ingraph``), none a sweep."""
         from repro.el.ingraph import check_ingraph_support
         cfg = self.cfg
         if mode is not None and cfg.mode != mode:
@@ -466,7 +474,8 @@ class ELSession:
         # are rejected by the support check below)
         if self._policy is not None and self._policy.name == "ol4el":
             cfg = dataclasses.replace(cfg, ucb_c=self._policy.ucb_c)
-        check_ingraph_support(cfg, self._require_executor(), caller=caller)
+        check_ingraph_support(cfg, self._require_executor(), caller=caller,
+                              single_run=mode is not None)
         return cfg
 
     @property
@@ -530,18 +539,25 @@ class ELSession:
 
     def _finish_ingraph(self, ex: EdgeExecutor, cfg: OL4ELConfig,
                         key: tuple, out: Dict[str, Any], params: Params,
-                        mode: str, horizon: int, t0: float) -> ELReport:
+                        mode: str, horizon: int, t0: float,
+                        to_host: bool = False) -> ELReport:
         """The host half of a compiled single run after its dispatch,
         one stage span each: the round records (and the callbacks), the
         final evaluation, the report.  Both are built from one host copy
         of ``out``: ``device_get`` starts every leaf's transfer before
         waiting on any, where reading a device array element by element
         costs a gather and a blocking copy per element.  ``params``
-        stays on the device for ``ex.evaluate``."""
+        stays on the device for ``ex.evaluate``, then, ``to_host``, goes
+        to the host for the report.  The records span carries the
+        program's counters where it has them: the local steps and
+        tokens each edge trained (``edge_steps``, ``edge_tokens``)."""
         from repro.obs import trace as obs_trace
         with obs_trace.span("session.records") as sp:
             out = jax.device_get(out)
             sp["host_bytes"] = sum(x.nbytes for x in jax.tree.leaves(out))
+            for counter in ("edge_steps", "edge_tokens"):
+                if counter in out:
+                    sp[counter] = out[counter]
             records: List[RoundRecord] = []
             for rec in records_from_out(out, 0, int(out["n_rounds"])):
                 self._emit(records, rec)
@@ -549,6 +565,8 @@ class ELSession:
         with obs_trace.span("session.evaluate", n=1):
             final = ex.evaluate(params)[self.metric_name]
         with obs_trace.span("session.report"):
+            if to_host:
+                params = jax.device_get(params)
             report = report_from_out(
                 out, mode=mode, policy=cfg.policy, horizon=horizon,
                 final_metric=final, final_params=params,
@@ -575,7 +593,8 @@ class ELSession:
                        shared in sync, per-edge in async)
         cost_model     ``fixed`` or ``variable`` (in-graph cost noise)
         utility        ``eval_gain`` (jittable metric) or ``param_delta``
-        executor       ``InGraphExecutor`` (e.g. ``ClassicExecutor``)
+        executor       ``InGraphExecutor`` (e.g. ``ClassicExecutor``;
+                       ``TokenLMExecutor``, a language model, here only)
         ============  =====================================================
 
         Unsupported (policy, cost_model, executor) combinations raise an
@@ -589,6 +608,9 @@ class ELSession:
         donates the initial params' buffers to the program (in-place
         fleet update); the caller must not reuse the passed-in params
         afterwards — the session detects a reuse attempt and raises.
+        Initial params held as numpy arrays are placed afresh for each
+        run and always donated, and the report's ``final_params`` come
+        back as numpy: a model the size of a chip is on it once per run.
 
         ``telemetry=`` switches the in-graph observability rings on
         (``repro.obs``: None/False off — today's program bit-for-bit;
@@ -608,20 +630,26 @@ class ELSession:
         process-wide; both default off (profiling costs one extra AOT
         compile per program).
         """
-        from repro.el.ingraph import (make_sync_program, sync_knob_names,
-                                      sync_knobs)
+        from repro.el.ingraph import (is_token_data, make_sync_program,
+                                      sync_knob_names, sync_knobs)
         from repro.obs import rings as obs_rings, trace as obs_trace
         with obs_trace.span("session.prepare") as sp:
             ex = self._require_executor()
             cfg = self._ingraph_cfg("run_sync_ingraph", mode="sync")
             spec = obs_rings.as_spec(telemetry)
             t0 = time.perf_counter()
+            params = self._initial_params()
+            # params kept on the host are placed afresh for every run:
+            # that copy is the program's own, so it is donated, and the
+            # trained params go back to the host (a device holds one
+            # run's params at a time, whatever their size)
+            on_host = _on_host(params)
+            donate = donate or on_host
             key = ("sync", ex, self._structural_cfg(cfg), max_rounds,
                    metric_fn, self.metric_name,
                    None if self._n_samples is None
                    else tuple(self._n_samples),
                    mesh, donate, spec)
-            params = self._initial_params()
             program = self._programs.get(key)
             sp["cache"] = "miss" if program is None else "hit"
             if program is None:
@@ -641,14 +669,15 @@ class ELSession:
                 (jax.eval_shape(lambda p: p, params),
                  jax.random.key(cfg.seed + 17), sync_knobs(cfg)),
                 mode="sync", mesh=mesh, donate=donate, profile=profile,
-                contract=contract, scenario=cfg.scenario is not None)
+                contract=contract, scenario=cfg.scenario is not None,
+                exchange=is_token_data(ex.edge_data))
         with obs_trace.span("session.dispatch", mode="sync") as sp:
             params, out = jax.block_until_ready(
                 program(params, jax.random.key(cfg.seed + 17),
                         sync_knobs(cfg)))
             sp["n_rounds"] = int(out["n_rounds"])
         return self._finish_ingraph(ex, cfg, key, out, params, "sync",
-                                    max_rounds, t0)
+                                    max_rounds, t0, to_host=on_host)
 
     @_session_call("async")
     def run_async_ingraph(self, max_events: Optional[int] = None,

@@ -12,6 +12,10 @@ a whole run into one XLA program.
 ``LMExecutor`` — small language models through the same interface (params
 only; per-edge optimizer moments are ephemeral within a local block, the
 standard local-SGD simplification).
+
+``TokenLMExecutor`` — a language model on per-edge token rows: what the
+compiled sync engine runs for an LM edge (``repro.el.InGraphExecutor``,
+plain-SGD ``model.local_step``, held-out negative loss).
 """
 
 from __future__ import annotations
@@ -125,4 +129,49 @@ class LMExecutor:
 
     def evaluate(self, params: Params) -> Dict[str, float]:
         loss = float(self._eval(params))
+        return {"loss": loss, "neg_loss": -loss}
+
+
+class TokenLMExecutor:
+    """A language model whose edges hold int32 token rows ``[N_e, S+1]``
+    (``edge_data[e]["tokens"]``), for ``ELSession.run_sync_ingraph``.
+
+    A local step is one plain SGD step on the next-token loss of
+    ``batch`` rows of the edge (``model.local_step``; no optimizer
+    state); the metric is the held-out negative loss of ``eval_tokens``
+    (``neg_loss``, and ``loss``).  ``local_train`` runs the same steps
+    for the host loops, on rows drawn by numpy."""
+
+    def __init__(self, model, edge_tokens: List[np.ndarray],
+                 eval_tokens: np.ndarray, batch: int = 2, lr: float = 0.1):
+        self.model = model
+        self.edge_data = [{"tokens": np.asarray(t, np.int32)}
+                          for t in edge_tokens]
+        self.eval_set = {"tokens": jnp.asarray(eval_tokens, jnp.int32)}
+        self.batch = batch
+        self.lr = lr
+        self._step = jax.jit(lambda p, toks: model.local_step(
+            p, {"tokens": toks}, lr)[0])
+        self._loss = jax.jit(model.token_loss)
+
+    def init_params(self, seed: int = 0) -> Params:
+        return self.model.init(jax.random.key(seed))
+
+    def local_train(self, params: Params, edge: int, n_iters: int,
+                    seed: int) -> Tuple[Params, Dict]:
+        toks = self.edge_data[edge]["tokens"]
+        rng = np.random.default_rng(seed)
+        for _ in range(int(n_iters)):
+            params = self._step(params, toks[rng.integers(
+                0, len(toks), size=self.batch)])
+        return params, {}
+
+    def evaluate(self, params: Params) -> Dict[str, float]:
+        # params replicated over a mesh are read on their first device:
+        # the model's kernels cannot be partitioned over the mesh
+        params = jax.tree.map(
+            lambda x: (x.addressable_shards[0].data
+                       if isinstance(x, jax.Array) and len(x.devices()) > 1
+                       and x.sharding.is_fully_replicated else x), params)
+        loss = float(self._loss(params, self.eval_set["tokens"]))
         return {"loss": loss, "neg_loss": -loss}

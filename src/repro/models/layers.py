@@ -125,8 +125,9 @@ def _qkv(params: Params, cfg: ModelConfig, x: jax.Array,
     if cfg.qk_norm:
         q = rms_norm(params["q_norm"], q, cfg.norm_eps)
         k = rms_norm(params["k_norm"], k, cfg.norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -193,7 +194,9 @@ def _blocked_attention(q, k, v, q_positions, k_positions, window, scale,
         return None, out
 
     iblk = jnp.arange(nblocks)
-    _, outs = lax.scan(body, None, (qb, pb, iblk))
+    # a block's logits are recomputed in the backward pass, not kept for
+    # every block: a training step holds one block's [Sq_blk, S] logits
+    _, outs = lax.scan(jax.checkpoint(body), None, (qb, pb, iblk))
     out = outs.transpose(1, 0, 2, 3, 4).reshape(b, nblocks * block_q, h, d)
     return out[:, :s]
 
@@ -203,7 +206,7 @@ def attention(params: Params, cfg: ModelConfig, x: jax.Array,
               window_slice: bool = False) -> jax.Array:
     """Full-sequence causal attention (train / prefill)."""
     q, k, v = _qkv(params, cfg, x, positions)
-    scale = cfg.resolved_head_dim ** -0.5
+    scale = cfg.attn_scale
     s = x.shape[1]
     if impl == "auto":
         impl = "naive" if s <= 2048 else "blocked"
@@ -232,7 +235,7 @@ def attention_fill(params: Params, cfg: ModelConfig, x: jax.Array,
     output as ``attention``.
     """
     q, k, v = _qkv(params, cfg, x, positions)
-    scale = cfg.resolved_head_dim ** -0.5
+    scale = cfg.attn_scale
     s = x.shape[1]
     cache_k = lax.dynamic_update_slice_in_dim(
         cache_k, k.astype(cache_k.dtype), 0, axis=1)
@@ -279,7 +282,7 @@ def attention_decode_ring(params: Params, cfg: ModelConfig, x: jax.Array,
         valid &= k_pos > (cache_index - cfg.sliding_window)
     valid = valid | (j == slot)              # the fresh token is always live
     bias = jnp.where(valid, 0.0, -1e30).astype(jnp.float32)[None, :]
-    scale = cfg.resolved_head_dim ** -0.5
+    scale = cfg.attn_scale
     out = _sdpa(q, cache_k.astype(q.dtype), cache_v.astype(q.dtype),
                 bias, scale)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
@@ -301,7 +304,7 @@ def attention_fill_ring(params: Params, cfg: ModelConfig, x: jax.Array,
     slots = jnp.mod(tail_pos, ring)
     cache_k = cache_k.at[:, slots].set(k[:, -n:].astype(cache_k.dtype))
     cache_v = cache_v.at[:, slots].set(v[:, -n:].astype(cache_v.dtype))
-    scale = cfg.resolved_head_dim ** -0.5
+    scale = cfg.attn_scale
     if impl == "auto":
         impl = "naive" if s <= 2048 else "blocked"
     if impl == "blocked":
@@ -338,7 +341,7 @@ def attention_decode(params: Params, cfg: ModelConfig, x: jax.Array,
         cache_k, k_new.astype(cache_k.dtype), cache_index, axis=1)
     cache_v = lax.dynamic_update_slice_in_dim(
         cache_v, v_new.astype(cache_v.dtype), cache_index, axis=1)
-    scale = cfg.resolved_head_dim ** -0.5
+    scale = cfg.attn_scale
     win = cfg.sliding_window
     if window_slice and 0 < win < s_max:
         span = win + 1                     # window ending at the new token

@@ -90,6 +90,13 @@ def _zero_aux() -> Dict[str, jax.Array]:
             "n_moe": z}
 
 
+def _residual(cfg: ModelConfig, y: jax.Array) -> jax.Array:
+    """A mixer's or FFN's output as it joins the residual stream."""
+    if cfg.residual_multiplier != 1.0:
+        return y * cfg.residual_multiplier
+    return y
+
+
 def apply_block(p: Params, cfg: ModelConfig, kind: str, ffn: str,
                 x: jax.Array, positions: jax.Array,
                 attn_impl: str = "auto", window_slice: bool = False,
@@ -98,23 +105,34 @@ def apply_block(p: Params, cfg: ModelConfig, kind: str, ffn: str,
     aux = _zero_aux()
     h = L.rms_norm(p["mix"]["norm"], x, cfg.norm_eps)
     if kind == ATTN:
-        x = x + L.attention(p["mix"], cfg, h, positions, impl=attn_impl,
-                            window_slice=window_slice)
+        x = x + _residual(cfg, L.attention(p["mix"], cfg, h, positions,
+                                           impl=attn_impl,
+                                           window_slice=window_slice))
     else:
-        x = x + M.mamba_mixer(p["mix"], cfg, h, use_kernel=use_ssd_kernel)
+        x = x + _residual(cfg, M.mamba_mixer(p["mix"], cfg, h,
+                                             use_kernel=use_ssd_kernel))
     if ffn == DENSE_FFN:
         h = L.rms_norm(p["ffn"]["norm"], x, cfg.norm_eps)
-        x = x + L.mlp(p["ffn"], h, cfg.act_fn)
+        x = x + _residual(cfg, L.mlp(p["ffn"], h, cfg.act_fn))
     elif ffn == MOE_FFN:
         h = L.rms_norm(p["ffn"]["norm"], x, cfg.norm_eps)
         y, moe_aux = MoE.moe_ffn(p["ffn"], cfg, h)
-        x = x + y
+        x = x + _residual(cfg, y)
         for k in ("load_balance_loss", "router_z_loss"):
             aux[k] = aux[k] + moe_aux[k]
         aux["expert_frac_max"] = jnp.maximum(aux["expert_frac_max"],
                                              moe_aux["expert_frac_max"])
         aux["n_moe"] = aux["n_moe"] + 1.0
     return x, aux
+
+
+def _remat_block(cfg: ModelConfig):
+    """``apply_block``, an activation checkpoint of its own when
+    ``cfg.remat``: the backward pass keeps each layer's input and
+    recomputes one layer at a time."""
+    if cfg.remat:
+        return jax.checkpoint(apply_block, static_argnums=(1, 2, 3, 6, 7, 8))
+    return apply_block
 
 
 def apply_block_decode(p: Params, cfg: ModelConfig, kind: str, ffn: str,
@@ -131,18 +149,18 @@ def apply_block_decode(p: Params, cfg: ModelConfig, kind: str, ffn: str,
             y, ck, cv = L.attention_decode(p["mix"], cfg, h, cache["k"],
                                            cache["v"], index,
                                            window_slice=window_slice)
-        x = x + y
+        x = x + _residual(cfg, y)
         cache = {"k": ck, "v": cv}
     else:
         y, cache = M.mamba_decode(p["mix"], cfg, h, cache)
-        x = x + y
+        x = x + _residual(cfg, y)
     if ffn == DENSE_FFN:
         h = L.rms_norm(p["ffn"]["norm"], x, cfg.norm_eps)
-        x = x + L.mlp(p["ffn"], h, cfg.act_fn)
+        x = x + _residual(cfg, L.mlp(p["ffn"], h, cfg.act_fn))
     elif ffn == MOE_FFN:
         h = L.rms_norm(p["ffn"]["norm"], x, cfg.norm_eps)
         y, _ = MoE.moe_ffn(p["ffn"], cfg, h)
-        x = x + y
+        x = x + _residual(cfg, y)
     return x, cache
 
 
@@ -158,19 +176,19 @@ def apply_block_fill(p: Params, cfg: ModelConfig, kind: str, ffn: str,
         y, ck, cv = fill(p["mix"], cfg, h, positions,
                          cache["k"], cache["v"], impl=attn_impl,
                          window_slice=window_slice)
-        x = x + y
+        x = x + _residual(cfg, y)
         cache = {"k": ck, "v": cv}
     else:
         y, cache = M.mamba_mixer_with_state(p["mix"], cfg, h,
                                             use_kernel=use_ssd_kernel)
-        x = x + y
+        x = x + _residual(cfg, y)
     if ffn == DENSE_FFN:
         h = L.rms_norm(p["ffn"]["norm"], x, cfg.norm_eps)
-        x = x + L.mlp(p["ffn"], h, cfg.act_fn)
+        x = x + _residual(cfg, L.mlp(p["ffn"], h, cfg.act_fn))
     elif ffn == MOE_FFN:
         h = L.rms_norm(p["ffn"]["norm"], x, cfg.norm_eps)
         y, _ = MoE.moe_ffn(p["ffn"], cfg, h)
-        x = x + y
+        x = x + _residual(cfg, y)
     return x, cache
 
 
@@ -268,6 +286,8 @@ class LM:
             x = jnp.sum(emb.astype(dtype)[cb, tokens], axis=1)   # [B, S, d]
         else:
             x = emb.astype(dtype)[tokens]                        # [B, S, d]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         if prefix_emb is not None:
             x = jnp.concatenate([prefix_emb.astype(dtype), x], axis=1)
         return x
@@ -276,22 +296,29 @@ class LM:
         cfg = self.cfg
         dtype = x.dtype
         if cfg.tie_embeddings:
-            return jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(dtype))
-        if cfg.n_codebooks > 1:
-            return jnp.einsum("bsd,cdv->bcsv", x,
-                              params["lm_head"].astype(dtype))
-        return x @ params["lm_head"].astype(dtype)
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                params["embed"].astype(dtype))
+        elif cfg.n_codebooks > 1:
+            logits = jnp.einsum("bsd,cdv->bcsv", x,
+                                params["lm_head"].astype(dtype))
+        else:
+            logits = x @ params["lm_head"].astype(dtype)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
 
     # -- forward (train / prefill) -------------------------------------------
 
     def _group_fn(self, p_group: Params, x: jax.Array, positions: jax.Array
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """The group's layers in turn (``_remat_block``)."""
         cfg = self.cfg
+        block = _remat_block(cfg)
         aux = _zero_aux()
         for i, (kind, ffn) in enumerate(self.group):
-            x, a = apply_block(p_group[f"sub{i}"], cfg, kind, ffn, x,
-                               positions, self.attn_impl, self.window_slice,
-                               self.use_ssd_kernel)
+            x, a = block(p_group[f"sub{i}"], cfg, kind, ffn, x,
+                         positions, self.attn_impl, self.window_slice,
+                         self.use_ssd_kernel)
             aux = jax.tree.map(jnp.add, aux, a)
         return x, aux
 
@@ -303,16 +330,15 @@ class LM:
         x = self.embed(params, tokens, prefix_emb)
         positions = jnp.arange(x.shape[1])
         aux = _zero_aux()
+        block = _remat_block(cfg)
         for p_layer, (kind, ffn) in zip(params.get("prefix_layers", []),
                                         self.prefix):
-            x, a = apply_block(p_layer, cfg, kind, ffn, x, positions,
-                               self.attn_impl, self.window_slice,
-                               self.use_ssd_kernel)
+            x, a = block(p_layer, cfg, kind, ffn, x, positions,
+                         self.attn_impl, self.window_slice,
+                         self.use_ssd_kernel)
             aux = jax.tree.map(jnp.add, aux, a)
         if self.n_groups:
             group_fn = self._group_fn
-            if cfg.remat:
-                group_fn = jax.checkpoint(group_fn)
             if cfg.scan_layers:
                 def body(carry, p_group):
                     x, aux = carry
@@ -376,6 +402,23 @@ class LM:
                    "load_balance_loss": aux["load_balance_loss"],
                    "router_z_loss": aux["router_z_loss"]}
         return loss, metrics
+
+    def token_loss(self, params: Params, tokens: jax.Array) -> jax.Array:
+        """Mean next-token cross entropy of ``tokens`` [B, S+1]: the
+        model reads the first S tokens and predicts the last S."""
+        logits, _ = self.forward(params, tokens[:, :-1])
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
+        return jnp.mean(nll)
+
+    def local_step(self, params: Params, batch: Dict[str, jax.Array],
+                   lr: float) -> Tuple[Params, Dict[str, jax.Array]]:
+        """One plain SGD step on ``token_loss`` over ``batch["tokens"]``:
+        the compiled EL engine's local step (no optimizer state)."""
+        loss, g = jax.value_and_grad(self.token_loss)(params,
+                                                      batch["tokens"])
+        return jax.tree.map(lambda p, d: p - lr * d, params, g), \
+            {"loss": loss}
 
     # -- serving -------------------------------------------------------------
 

@@ -387,10 +387,18 @@ DEFAULT_GATHER_RANGE: Tuple[int, int] = (1, 16)
 SCENARIO_REDUCE_RANGE: Tuple[int, int] = (0, 32)
 
 
+#: collective range of a language model's sync round on a mesh: an
+#: all-to-all and an all-gather for each parameter leaf where the edges
+#: tile the mesh (XLA may split a leaf's into several), none where every
+#: chip runs every edge
+EXCHANGE_RANGE: Tuple[int, int] = (0, 4096)
+
+
 def default_contract(*, mesh=None, donated: bool = False,
                      param_bytes: Optional[int] = None,
                      mode: str = "sync",
-                     scenario: bool = False) -> CollectiveContract:
+                     scenario: bool = False,
+                     exchange: bool = False) -> CollectiveContract:
     """The contract every compiled EL program is expected to satisfy.
 
     * no mesh (or a 1-device mesh): NO collectives of any kind;
@@ -401,6 +409,11 @@ def default_contract(*, mesh=None, donated: bool = False,
     * ``scenario`` (a ``ScenarioSpec``-path program) on a multi-device
       mesh: additionally up to ``SCENARIO_REDUCE_RANGE[1]`` all-reduces
       — the scalar churn-mask reductions over the sharded edge axis;
+    * ``exchange`` (a language model's sync round,
+      ``repro.el.ingraph.make_token_round``) on a multi-device mesh: the
+      edge stack never leaves its chips — all-to-alls and all-gathers of
+      the per-leaf means (``EXCHANGE_RANGE`` each), still no all-reduce
+      or reduce-scatter (the mean's order is the unsharded one);
     * ``donated`` with ``param_bytes``: the whole param tree aliased
       (``alias_bytes == param_bytes``); non-donated: ``== 0``.
     """
@@ -408,8 +421,12 @@ def default_contract(*, mesh=None, donated: bool = False,
     if mesh is not None:
         import numpy as np
         n_dev = int(np.asarray(mesh.devices).size)
-    if n_dev > 1:
+    if n_dev > 1 and exchange:
         counts: Dict[str, CountConstraint] = {
+            "all-gather": EXCHANGE_RANGE, "all-to-all": EXCHANGE_RANGE,
+            "all-reduce": 0, "reduce-scatter": 0}
+    elif n_dev > 1:
+        counts = {
             "all-gather": DEFAULT_GATHER_RANGE,
             "all-reduce": (SCENARIO_REDUCE_RANGE if scenario else 0),
             "reduce-scatter": 0, "all-to-all": 0}
@@ -421,6 +438,8 @@ def default_contract(*, mesh=None, donated: bool = False,
     elif not donated:
         alias = 0
     tag = "sharded" if n_dev > 1 else "replicated"
+    if exchange:
+        tag += "-exchange"
     if scenario:
         tag += "-scenario"
     return CollectiveContract(

@@ -298,20 +298,26 @@ def el_stacked_param_specs(mesh: Mesh, n_edges: int,
     replicate).  ``stacked_params`` may hold tracers — only ``.shape``
     is read, so this works at trace time inside the compiled programs.
 
-    Scanned-LM group stacking (``param_specs``' ``groups`` rule) is NOT
-    handled here: the compiled EL programs only admit flat
-    ``InGraphExecutor`` param trees today (``check_ingraph_support``);
-    staging an LM executor in-graph must teach this function the extra
-    ``n_groups`` dim first.
+    A scanned LM's group leaves (under ``groups``, a dict: ``[E,
+    n_groups, ...]``) keep the stacked ``n_groups`` dim unsharded, as
+    ``param_specs``' ``groups`` rule does.  The compiled sync engine
+    runs a language model's edges without this stack
+    (``repro.el.ingraph.make_token_round``); these specs are the
+    classic gather path's, which any tree can take.
     """
     ms = _axis_size(mesh, "model")
     ea = el_edge_dim_axes(mesh.axis_names, dict(
         zip(mesh.axis_names, mesh.devices.shape)), n_edges)
 
     def leaf_spec(path, leaf) -> P:
-        name = _leaf_param_name(_leaf_path_keys(path))
-        base = _param_spec(name, leaf.shape[1:], ms)
-        return P(ea, *base)
+        keys = _leaf_path_keys(path)
+        name = _leaf_param_name(keys)
+        # a scanned model keeps its groups in a dict of stacked leaves,
+        # an unrolled one in a list (no extra dim)
+        stacked = ("groups" in keys
+                   and isinstance(keys[keys.index("groups") + 1], str))
+        base = _param_spec(name, leaf.shape[1 + stacked:], ms)
+        return P(ea, *([None] * stacked), *base)
 
     return jax.tree_util.tree_map_with_path(leaf_spec, stacked_params)
 

@@ -17,6 +17,7 @@ EXPECTED_DIMS = {
     "deepseek-coder-33b": (62, 7168, 56, 8, 19200, 32256),
     "olmoe-1b-7b": (16, 2048, 16, 16, 1024, 50304),
     "qwen3-1.7b": (28, 2048, 16, 8, 6144, 151936),
+    "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
 }
 
 EXPECTED_MOE = {

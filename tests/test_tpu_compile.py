@@ -1,4 +1,4 @@
-"""Compile the main path's Pallas kernel for a described TPU v5e.
+"""Compile the main path's Pallas kernels for a described TPU v5e.
 
 No chip is needed: the TPU compiler builds for a topology that is
 described, not attached, and refuses what the chip would refuse (block
@@ -74,6 +74,30 @@ def test_kmeans_assign_compiles_whole_dataset(one_chip, n, d, k):
     x = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)
     c = jax.ShapeDtypeStruct((k, d), jnp.float32, sharding=one_chip)
     assert "tpu_custom_call" in _hlo(_native, x, c)
+
+
+@pytest.mark.parametrize("b,s,h,p,n,chunk", [
+    (2, 4096, 64, 64, 128, 256),   # granite-4.0-h-micro's Mamba-2 layer
+    (1, 512, 32, 64, 128, 128),    # mamba2-370m's heads
+])
+def test_ssd_scan_compiles_at_published_widths(one_chip, b, s, h, p, n,
+                                               chunk):
+    """The SSD kernel's forward at a model's widths, under the float32
+    ``high`` default a configuration may set (the kernel takes its own
+    products at ``highest``)."""
+    from repro.kernels.ssd_scan.kernel import ssd_fwd
+
+    def sd(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    prev = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "high")
+    try:
+        hlo = _hlo(lambda x, da, bm, cm: ssd_fwd(x, da, bm, cm, chunk),
+                   sd(b, s, h, p), sd(b, s, h), sd(b, s, n), sd(b, s, n))
+    finally:
+        jax.config.update("jax_default_matmul_precision", prev)
+    assert "tpu_custom_call" in hlo
 
 
 def test_collective_census_reads_tpu_hlo(mesh_2x2):
