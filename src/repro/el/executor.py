@@ -51,7 +51,10 @@ class InitCapable(Protocol):
 class InGraphExecutor(Protocol):
     """What ``ELSession.run_sync_ingraph`` additionally needs: the jittable
     model plus raw per-edge datasets so the whole budgeted loop can be
-    staged into one XLA program."""
+    staged into one XLA program.  An executor may also offer
+    ``evaluate_many(stacked_params) -> {name: [n_cells]}`` (or None):
+    a sweep without an in-graph metric then scores all its cells in one
+    call instead of one ``evaluate`` per cell."""
 
     model: Any
     edge_data: List[Dict[str, np.ndarray]]

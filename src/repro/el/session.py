@@ -877,12 +877,12 @@ class ELSession:
                 out=out, policy=cfg.policy,
                 elapsed_s=time.perf_counter() - t0, final_params=params)
         # workloads without a jittable metric (e.g. K-means F1) run the
-        # program with NaN metric history; score the final params host-side
-        # so the report's frontier still has an accuracy axis
+        # program with NaN metric history; score the final params after it
+        # (every cell in one call where the executor offers a batched
+        # form) so the report's frontier still has an accuracy axis
         with obs_trace.span("session.evaluate") as sp:
-            scored = report.score_final_params(
-                lambda p: ex.evaluate(p)[self.metric_name])
-            sp["n"] = report.n_cells if scored else 0
+            sp["n"], sp["batched"] = report.score_final_params(
+                ex, self.metric_name)
         return report
 
     # -- AC-sync estimator plumbing -------------------------------------------

@@ -79,20 +79,30 @@ class SweepReport:
             return np.asarray(self.out["final_metric_host"], np.float64)
         return vals
 
-    def score_final_params(self, eval_fn) -> bool:
+    def score_final_params(self, ex, metric_name: str) -> Tuple[int, int]:
         """Host-side scoring fallback: when the compiled program had no
         in-graph metric (all-NaN history), score each cell's final params
-        with ``eval_fn(params) -> float`` and record the results.  No-op
-        (returns False) when the in-graph metric exists."""
+        by ``ex``'s ``metric_name`` and record the results.  Where the
+        executor offers a batched form (``ex.evaluate_many(stacked) ->
+        {name: [n_cells]}``) every cell is scored by that one call;
+        otherwise ``ex.evaluate`` runs once per cell.  Returns (cells
+        scored, cells scored by the batched call): (0, 0) when the
+        in-graph metric exists."""
         if self.final_params is None:
-            return False
+            return 0, 0
         if not np.isnan(self._at_last_round("metric")).all():
-            return False
+            return 0, 0
+        many = getattr(ex, "evaluate_many", None)
+        scores = None if many is None else many(self.final_params)
+        if scores is not None:
+            self.out["final_metric_host"] = np.asarray(
+                scores[metric_name], np.float64)
+            return self.n_cells, self.n_cells
         import jax
         self.out["final_metric_host"] = np.asarray(
-            [eval_fn(jax.tree.map(lambda x: x[i], self.final_params))
-             for i in range(self.n_cells)], np.float64)
-        return True
+            [ex.evaluate(jax.tree.map(lambda x: x[i], self.final_params))
+             [metric_name] for i in range(self.n_cells)], np.float64)
+        return self.n_cells, 0
 
     def total_consumed(self) -> np.ndarray:
         """Total resource consumed (summed over edges), [n_cells]."""

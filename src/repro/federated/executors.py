@@ -21,7 +21,7 @@ plain-SGD ``model.local_step``, held-out negative loss).
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +76,16 @@ class ClassicExecutor:
 
     def evaluate(self, params: Params) -> Dict[str, float]:
         return self.model.evaluate(params, self.eval_set)
+
+    def evaluate_many(self, stacked_params: Params
+                      ) -> Optional[Dict[str, np.ndarray]]:
+        """``evaluate`` for every cell of a stacked ``[n_cells, ...]``
+        params tree at once, as ``[n_cells]`` arrays; None where the
+        model has no batched form (the caller then scores cell by
+        cell)."""
+        many = getattr(self.model, "evaluate_many", None)
+        return None if many is None else many(stacked_params,
+                                              self.eval_set)
 
 
 class LMExecutor:

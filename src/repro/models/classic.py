@@ -101,6 +101,12 @@ class KMeans:
         self.k = cfg.vocab_size
         self.blend = blend           # minibatch-Lloyd blending rate
         self.impl = "pallas" if use_kernel else impl
+        # every set of a stacked [n, K, D] centers tree scored in one
+        # compiled call (``evaluate_many``); traced once per shape
+        self._score_many = jax.jit(jax.vmap(
+            lambda params, x: (self.assign(params, x),
+                               self.inertia(params, x)),
+            in_axes=(0, None)))
 
     @property
     def use_kernel(self) -> bool:   # pre-impl= spelling, kept for callers
@@ -156,6 +162,24 @@ class KMeans:
         f1 = cluster_f1(a, y, self.k)
         inert = float(self.inertia(params, jnp.asarray(x)))
         return {"f1": f1, "inertia": inert}
+
+    def evaluate_many(self, stacked: Params, eval_set: Dict[str, jax.Array]
+                      ) -> Dict[str, np.ndarray]:
+        """``evaluate`` for every set of centers in a stacked ``[n, K, D]``
+        tree, as ``[n]`` arrays.  One compiled call assigns the eval
+        points to each set (``assign``: the same E-step engine, so the
+        same assignments) and takes its inertia; one host copy brings
+        back the ``[n, N]`` assignments, the inertias and the labels;
+        the F1 matching runs on the host per set.  The centers are put
+        where the eval points live, so the call is one device's program
+        even when they arrive sharded over a mesh (a Pallas kernel
+        cannot be partitioned)."""
+        x = jnp.asarray(eval_set["x"])
+        stacked = jax.device_put(stacked, x.sharding)
+        a, inert, y = jax.device_get(
+            (*self._score_many(stacked, x), eval_set["y"]))
+        return {"f1": np.array([cluster_f1(ai, y, self.k) for ai in a]),
+                "inertia": np.asarray(inert, np.float64)}
 
 
 def cluster_f1(assignments: np.ndarray, labels: np.ndarray, k: int) -> float:

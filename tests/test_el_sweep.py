@@ -290,10 +290,10 @@ def test_report_to_rows_flat_contract():
 # ---------------------------------------------------------------------------
 
 
-def test_kmeans_sweep_scores_final_params_host_side():
+def _kmeans_sweep(impl="jnp"):
     train, test = make_traffic_dataset(n=600)
     exp = get_config("kmeans-traffic")
-    model = build_model(exp.model)
+    model = build_model(exp.model, impl=impl)
     ol = dataclasses.replace(exp.ol4el, mode="sync", policy="ol4el",
                              n_edges=2, budget=500.0, heterogeneity=2.0,
                              utility="param_delta")
@@ -301,11 +301,36 @@ def test_kmeans_sweep_scores_final_params_host_side():
     ex = ClassicExecutor(model, edges, test, batch=128, lr=1.0)
     sess = (ELSession(ol, metric_name="f1", lr=1.0)
             .with_executor(ex, init_params=model.init(jax.random.key(1))))
+    return ex, sess
+
+
+def test_kmeans_sweep_scores_final_params_host_side():
+    _, sess = _kmeans_sweep()
     rep = sess.sweep(SweepSpec(seeds=(0, 1), max_rounds=32))
     assert "final_metric_host" in rep.out
     finals = rep.final_metrics()
     assert finals.shape == (2,)
     assert np.isfinite(finals).all() and (finals > 0.3).all()
+
+
+@pytest.mark.parametrize("impl", ("jnp", "pallas"))
+def test_kmeans_batched_scores_equal_cell_by_cell_evaluate(impl):
+    """The sweep scores its cells in one compiled call over the cell
+    axis; each cell's F1 is the per-cell ``evaluate``'s bit for bit (the
+    same E-step engine gives the same assignments, the same host
+    matching the same F1) and its inertia agrees to float32 rounding."""
+    ex, sess = _kmeans_sweep(impl)
+    rep = sess.sweep(SweepSpec(heterogeneity=(1.0, 4.0), seeds=(0, 1, 2),
+                               max_rounds=32))
+    per_cell = [ex.evaluate(jax.tree.map(lambda x: x[i], rep.final_params))
+                for i in range(rep.n_cells)]
+    many = ex.evaluate_many(rep.final_params)
+    f1 = np.array([m["f1"] for m in per_cell])
+    assert len(set(f1)) > 1                  # the cells are told apart
+    np.testing.assert_array_equal(many["f1"], f1)
+    np.testing.assert_allclose(many["inertia"],
+                               [m["inertia"] for m in per_cell], rtol=1e-6)
+    np.testing.assert_array_equal(rep.out["final_metric_host"], f1)
 
 
 # ---------------------------------------------------------------------------

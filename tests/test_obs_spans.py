@@ -209,6 +209,46 @@ def test_session_call_holds_its_stage_spans(entry, tracer, request):
                 "n_rounds" if entry == "sync" else "n_events")
 
 
+def _sweep_twice(session, tracer):
+    """Two sweeps of one grid shape (other seeds the second time); the
+    report of each and its ``session.evaluate`` span."""
+    reports = [session.sweep(SweepSpec(heterogeneity=(1.0, 9.0),
+                                       seeds=(seed,), max_rounds=16))
+               for seed in (0, 1)]
+    return reports, tracer.events("session.evaluate")[-2:]
+
+
+@pytest.mark.parametrize("impl", ("jnp", "pallas"))
+def test_sweep_scores_every_cell_in_one_compiled_call(impl, tracer):
+    """The sweep's cells are scored by the executor's batched form: a
+    second sweep of the same grid shape compiles nothing there."""
+    fx = classic_fixture("kmeans-traffic", samples=128, n_edges=4,
+                         alpha=100.0, data_seed=0, kmeans_impl=impl)
+    reports, (first, second) = _sweep_twice(_session(fx, "sync"), tracer)
+    for rep, span in zip(reports, (first, second)):
+        assert span["n"] == span["batched"] == rep.n_cells == 2
+    assert first["compiles"] >= 1
+    assert second["compiles"] == 0
+
+
+def test_sweep_without_a_batched_form_scores_cell_by_cell(kmeans, tracer):
+    """An executor that offers no batched form still scores every cell,
+    one ``evaluate`` each, to the same numbers."""
+    ex = kmeans["executor"]
+
+    class CellByCell(type(ex)):
+        evaluate_many = None
+
+    per_cell = dict(kmeans, executor=CellByCell(
+        ex.model, ex.edge_data, ex.eval_set, batch=ex.batch, lr=ex.lr))
+    reports, spans = _sweep_twice(_session(per_cell, "sync"), tracer)
+    assert [(s["n"], s["batched"]) for s in spans] == [(2, 0), (2, 0)]
+    batched, _ = _sweep_twice(_session(kmeans, "sync"), tracer)
+    for rep, ref in zip(reports, batched):
+        np.testing.assert_array_equal(rep.out["final_metric_host"],
+                                      ref.out["final_metric_host"])
+
+
 @pytest.mark.parametrize("mode", ("sync", "async"))
 def test_records_are_built_from_one_host_copy(mode, svm, tracer,
                                               monkeypatch):

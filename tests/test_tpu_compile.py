@@ -76,6 +76,28 @@ def test_kmeans_assign_compiles_whole_dataset(one_chip, n, d, k):
     assert "tpu_custom_call" in _hlo(_native, x, c)
 
 
+def test_kmeans_sweep_scorer_compiles_over_the_cell_axis(one_chip,
+                                                          monkeypatch):
+    """``KMeans.evaluate_many``'s compiled call at the sweep cell's
+    shapes (63 cells of [3, 64] centers against the 4,000-row eval set,
+    unbatched) runs the kernel natively, at the cell's ``highest``."""
+    from repro.config import get_config
+    from repro.kernels.kmeans_assign import ops as ka_ops
+    from repro.models import build_model
+    monkeypatch.setattr(ka_ops, "interpret_default", lambda: False)
+    model = build_model(get_config("kmeans-traffic").model, impl="pallas")
+    centers = {"centers": jax.ShapeDtypeStruct((63, 3, 64), jnp.float32,
+                                               sharding=one_chip)}
+    x = jax.ShapeDtypeStruct((4000, 64), jnp.float32, sharding=one_chip)
+    prev = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    try:
+        hlo = model._score_many.lower(centers, x).compile().as_text()
+    finally:
+        jax.config.update("jax_default_matmul_precision", prev)
+    assert "tpu_custom_call" in hlo
+
+
 @pytest.mark.parametrize("b,s,h,p,n,chunk", [
     (2, 4096, 64, 64, 128, 256),   # granite-4.0-h-micro's Mamba-2 layer
     (1, 512, 32, 64, 128, 128),    # mamba2-370m's heads
