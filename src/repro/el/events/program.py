@@ -76,6 +76,14 @@ from repro.el.ingraph import (ELCell, _edge_stack_constraints,
 Params = Any
 
 
+def _if_live(is_act, x, other):
+    """``x``, or ``other`` where a scenario's activity bit ``is_act`` is
+    off (a dropped edge's probe); with ``is_act`` None (no scenario) it
+    traces no op.  ``other`` is evaluated either way: pass a constant or
+    a value already computed."""
+    return x if is_act is None else jnp.where(is_act, x, other)
+
+
 def _build_parts(model, edge_data, eval_set, cfg: OL4ELConfig, *,
                  lr: float, batch: int, metric_fn: Optional[Callable],
                  metric_name: str, mesh=None, drift: bool = False):
@@ -140,7 +148,9 @@ def make_async_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
     docstring); ``None`` resolves it from the config and mesh
     (``resolve_async_batch_k``).  ``batch_k=1`` builds exactly the
     single-event argmin-pop body; ``> 1`` builds the order-equivalent
-    wave body.
+    wave body.  Both run one event's control plane through the same
+    ``event_step`` chain.  A ``cfg.scenario`` adds static branches to
+    the single-event body (see ``event_step``) and needs ``batch_k=1``.
     """
     from repro.obs.rings import (as_spec, async_ring_init,
                                  async_ring_record,
@@ -149,9 +159,9 @@ def make_async_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
     spec = as_spec(telemetry)
     del n_samples
     check_ingraph_support(cfg, caller="make_async_program")
-    # fleet-dynamics scenario: None keeps every closure below EXACTLY
-    # today's traced code; a ScenarioSpec swaps in the churn-aware
-    # single-event body (dropout probes, uncharged dead edges).
+    # fleet-dynamics scenario: static branches of the single-event
+    # body (dropout probes, uncharged dead edges), taken only with a
+    # ScenarioSpec, so None traces no scenario op at all
     scn = cfg.scenario
     period = scn.period if scn is not None else 0
 
@@ -239,91 +249,164 @@ def make_async_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
         return ((carry["t"] < cap)
                 & jnp.any(jnp.isfinite(carry["finish"])))
 
+    def event_step(state, knobs, e, wall, interval, cost, p_new, k_sel,
+                   k_cost, is_act=None, mult=None):
+        """One event's control plane: charge edge ``e`` its block's
+        ``cost`` (the realized-cost draw that set the finish time, so
+        charged == scheduled), merge its trained block ``p_new`` into
+        the global model with the staleness-weighted alpha, evaluate,
+        update and place ``e``'s bandit, record the version ``e``
+        fetches and schedule its next block from ``wall``.  ``state`` is
+        ``(gparams, fleet, consumed, fetch_ver, version, prev_metric)``;
+        returns the next state and the event's values.  ``body_one``
+        runs it once per step and ``body_wave`` once per lane.
+
+        With a scenario, ``is_act`` is the edge's activity bit: a
+        dropped edge's event is a PROBE — no merge, no charge, no bandit
+        pull, no version bump — that retries the same in-flight block
+        after a reconnect delay, so churned edges burn wall clock but
+        never budget; ``mult`` is the edge's straggler multiplier, which
+        scales its next block's cost surface at scheduling time (cost =
+        m * (i*comp + comm) by linearity).
+        """
+        gparams, fleet, consumed, fetch_ver, version, prev_metric = state
+        charge = _if_live(is_act, cost, 0.0)
+        consumed = consumed.at[e].add(charge)
+        alpha = staleness_alpha(knobs["async_alpha"], version, fetch_ver[e],
+                                n_edges)
+        stale = None
+        if spec is not None or batch_k > 1:
+            # the raw staleness (staleness_alpha's exact f32 expression)
+            # for the telemetry ring; a wave stacks it for every lane
+            stale = ((version - fetch_ver[e]).astype(jnp.float32)
+                     / jnp.float32(max(n_edges, 1)))
+        new_global = staleness_merge(gparams, p_new, alpha)
+        if is_act is not None:
+            new_global = jax.tree.map(
+                lambda m, g: jnp.where(is_act, m, g), new_global, gparams)
+        version = version + _if_live(is_act, 1, 0)
+        metric, utility = eval_step(new_global, gparams, prev_metric)
+        # arm -1 makes the bandit update a no-op (its valid guard), so a
+        # probe pulls nothing
+        bstate_e = jax_bandit_update(bandit_slice(fleet, e),
+                                     _if_live(is_act, interval - 1, -1),
+                                     utility, cost)
+        fleet = bandit_place(fleet, e, bstate_e)
+        fetch_ver = fetch_ver.at[e].set(
+            version if is_act is None
+            else jnp.where(is_act, version, fetch_ver[e]))
+        resid = knobs["budget"] - consumed[e]
+        scale = (lambda x: x) if mult is None else (lambda x: x * mult)
+        _, nxt_i, nxt_c, fin = schedule_block(
+            bstate_e, resid, scale(knobs["costs_ek"][e]), knobs["ucb_c"],
+            scale(knobs["min_edge_cost"][e]), knobs["cost_noise"],
+            scale(knobs["comp"][e]), scale(knobs["comm"][e]), wall,
+            jax.random.fold_in(k_sel, e),
+            jax.random.fold_in(k_cost, e))
+        if is_act is not None:
+            # a probe keeps its in-flight block and retries after a
+            # reconnect delay of the edge's minimum block cost
+            fin = jnp.where(is_act, fin, wall + knobs["min_edge_cost"][e])
+            nxt_i = jnp.where(is_act, nxt_i, interval)
+            nxt_c = jnp.where(is_act, nxt_c, cost)
+        outs = {"metric": metric, "utility": utility,
+                "interval": interval, "cost": charge,
+                "consumed_sum": jnp.sum(consumed),
+                "resid": resid, "alpha": alpha, "stale": stale,
+                "bcounts": bstate_e["counts"],
+                "butil": bstate_e["utility_sum"],
+                "nxt_i": nxt_i, "nxt_c": nxt_c, "fin": fin,
+                "new_global": new_global}
+        return (new_global, fleet, consumed, fetch_ver, version,
+                metric), outs
+
     def body_one(carry: Dict[str, Any], knobs: Dict[str, jax.Array]):
-        ucb_c, budget = knobs["ucb_c"], knobs["budget"]
-        costs_ek = knobs["costs_ek"]                            # [E, K]
-        alpha0 = knobs["async_alpha"]
-        gparams, edge_params = carry["gparams"], carry["edge_params"]
-        fleet, consumed = carry["fleet"], carry["consumed"]
         finish = carry["finish"]
         infl_i, infl_c = carry["infl_i"], carry["infl_c"]
-        fetch_ver, version = carry["fetch_ver"], carry["version"]
-        t, prev_metric = carry["t"], carry["prev_metric"]
-        hist = carry["hist"]
+        t, hist = carry["t"], carry["hist"]
 
         rng, k_sel, k_data, k_cost = split_event_keys(carry["rng"])
         # the event horizon: the earliest-finishing in-flight block
         e = jnp.argmin(finish)
         wall = finish[e]
+        # the event edge's activity bit; None without a scenario
+        is_act = mult = None
+        if scn is not None:
+            slot_i = jnp.mod(t, period)
+            act_row = knobs["scn_active"][slot_i] > 0            # [E]
+            is_act = act_row[e]
         interval, cost = infl_i[e], infl_c[e]
         # edge e finishes `interval` local iterations and uploads;
         # its slice of the sharded stack is gathered replicated so
         # the block/merge arithmetic runs identically on every
         # device (the event path is control plane)
         p_e = gather_edge_stack(jax.tree.map(lambda a: a[e],
-                                             edge_params))
-        p_new = local_block(p_e, e, interval,
-                            jax.random.fold_in(k_data, e))
-        # the SAME realized-cost draw set the finish time and is
-        # charged at completion (charged == scheduled)
-        consumed = consumed.at[e].add(cost)
-        alpha = staleness_alpha(alpha0, version, fetch_ver[e], n_edges)
-        if spec is not None:
-            # the raw staleness (staleness_alpha's exact f32
-            # expression), recorded in the telemetry ring below
-            stale = ((version - fetch_ver[e]).astype(jnp.float32)
-                     / jnp.float32(max(n_edges, 1)))
-        new_global = staleness_merge(gparams, p_new, alpha)
-        version = version + 1
-        metric, utility = eval_step(new_global, gparams, prev_metric)
-        bstate_e = jax_bandit_update(bandit_slice(fleet, e),
-                                     interval - 1, utility, cost)
-        fleet = bandit_place(fleet, e, bstate_e)
-        # edge fetches the fresh global model, schedules next block
-        # (the scatter re-pins the stack's sharding so the
-        # while-loop carry layout is stable across iterations)
+                                             carry["edge_params"]))
+        k_data_e = jax.random.fold_in(k_data, e)
+        if is_act is None:
+            p_new = local_block(p_e, e, interval, k_data_e)
+        else:
+            # a dropped edge runs zero masked work (interval 0) and the
+            # drift shift rotates the sampling window
+            shift = knobs["scn_drift"] * t.astype(jnp.float32)
+            p_new = local_block(p_e, e, jnp.where(is_act, interval, 0),
+                                k_data_e, shift)
+            mult = knobs["scn_mult"][slot_i, e]
+        state, ev = event_step(
+            (carry["gparams"], carry["fleet"], carry["consumed"],
+             carry["fetch_ver"], carry["version"], carry["prev_metric"]),
+            knobs, e, wall, interval, cost, p_new, k_sel, k_cost,
+            is_act, mult)
+        gparams, fleet, consumed, fetch_ver, version, metric = state
+        # the edge fetches the fresh global model (a probe keeps its
+        # own); the scatter re-pins the stack's sharding so the
+        # while-loop carry layout is stable across iterations
         edge_params = constrain_edge_stack(jax.tree.map(
-            lambda a, g: a.at[e].set(g), edge_params, new_global))
-        fetch_ver = fetch_ver.at[e].set(version)
-        resid = budget - consumed[e]
-        _, nxt_i, nxt_c, fin = schedule_block(
-            bstate_e, resid, costs_ek[e], ucb_c,
-            knobs["min_edge_cost"][e], knobs["cost_noise"],
-            knobs["comp"][e], knobs["comm"][e], wall,
-            jax.random.fold_in(k_sel, e),
-            jax.random.fold_in(k_cost, e))
-        finish = finish.at[e].set(fin)
-        infl_i = infl_i.at[e].set(nxt_i)
-        infl_c = infl_c.at[e].set(nxt_c)
+            lambda a, g: a.at[e].set(
+                g if is_act is None else jnp.where(is_act, g, a[e])),
+            carry["edge_params"], gparams))
+        finish = finish.at[e].set(ev["fin"])
+        infl_i = infl_i.at[e].set(ev["nxt_i"])
+        infl_c = infl_c.at[e].set(ev["nxt_c"])
         hist = {
             "metric": hist["metric"].at[t].set(metric),
-            "utility": hist["utility"].at[t].set(utility),
-            "interval": hist["interval"].at[t].set(interval),
+            "utility": hist["utility"].at[t].set(
+                _if_live(is_act, ev["utility"], 0.0)),
+            "interval": hist["interval"].at[t].set(
+                _if_live(is_act, interval, 0)),
             "edge": hist["edge"].at[t].set(e.astype(jnp.int32)),
-            "cost": hist["cost"].at[t].set(cost),
-            "consumed": hist["consumed"].at[t].set(jnp.sum(consumed)),
+            "cost": hist["cost"].at[t].set(ev["cost"]),
+            "consumed": hist["consumed"].at[t].set(ev["consumed_sum"]),
             "wall": hist["wall"].at[t].set(wall),
         }
-        new_carry = {"gparams": new_global, "edge_params": edge_params,
+        if scn is not None:
+            n_act_fleet = jnp.sum(act_row.astype(jnp.int32))
+            hist["active_edges"] = carry["hist"]["active_edges"].at[
+                t].set(n_act_fleet)
+        new_carry = {"gparams": gparams, "edge_params": edge_params,
                      "fleet": fleet, "consumed": consumed,
                      "finish": finish, "infl_i": infl_i,
                      "infl_c": infl_c, "fetch_ver": fetch_ver,
                      "version": version, "t": t + 1, "rng": rng,
                      "prev_metric": metric, "wall": wall, "hist": hist}
         if spec is not None:
+            scn_counts = None
+            if scn is not None:
+                scn_counts = (n_act_fleet, 1 - is_act.astype(jnp.int32),
+                              jnp.int32(0))
             with jax.named_scope("obs.telemetry"):
                 new_carry["telem"] = async_ring_record(
                     carry["telem"], spec, t=t, edge=e,
-                    arm=interval - 1, cost=cost, budget_resid=resid,
-                    alpha=alpha, staleness=stale,
+                    arm=interval - 1, cost=ev["cost"],
+                    budget_resid=ev["resid"], alpha=ev["alpha"],
+                    staleness=ev["stale"],
                     interarrival=wall - carry["wall"],
-                    bstate_e=bstate_e)
+                    bstate_e={"counts": ev["bcounts"],
+                              "utility_sum": ev["butil"]},
+                    scn=scn_counts)
         return new_carry
 
     def body_wave(carry: Dict[str, Any], knobs: Dict[str, jax.Array]):
-        ucb_c, budget = knobs["ucb_c"], knobs["budget"]
-        costs_ek = knobs["costs_ek"]                            # [E, K]
-        alpha0 = knobs["async_alpha"]
         edge_params = carry["edge_params"]
         finish = carry["finish"]
         infl_i, infl_c = carry["infl_i"], carry["infl_c"]
@@ -387,43 +470,12 @@ def make_async_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
 
         # -- control plane: the merge chain is inherently sequential
         # (lane j+1 merges into lane j's global), so replay it per lane
-        # under a validity mask — the exact op sequence of batch_k=1.
+        # under a validity mask: event_step, as batch_k=1 runs it.
         def lane_step(j, state):
-            (gparams, fleet, consumed, fetch_ver, version,
-             prev_metric) = state
-            e = e_sorted[j]
-            wall_j = f_sorted[j]
-            interval, cost = interval_l[j], cost_l[j]
-            p_new = jax.tree.map(lambda a: a[j], p_new_stack)
-            consumed = consumed.at[e].add(cost)
-            alpha = staleness_alpha(alpha0, version, fetch_ver[e],
-                                    n_edges)
-            stale = ((version - fetch_ver[e]).astype(jnp.float32)
-                     / jnp.float32(max(n_edges, 1)))
-            new_global = staleness_merge(gparams, p_new, alpha)
-            version = version + 1
-            metric, utility = eval_step(new_global, gparams, prev_metric)
-            bstate_e = jax_bandit_update(bandit_slice(fleet, e),
-                                         interval - 1, utility, cost)
-            fleet = bandit_place(fleet, e, bstate_e)
-            fetch_ver = fetch_ver.at[e].set(version)
-            resid = budget - consumed[e]
-            _, nxt_i, nxt_c, fin = schedule_block(
-                bstate_e, resid, costs_ek[e], ucb_c,
-                knobs["min_edge_cost"][e], knobs["cost_noise"],
-                knobs["comp"][e], knobs["comm"][e], wall_j,
-                jax.random.fold_in(k_sels[j], e),
-                jax.random.fold_in(k_costs[j], e))
-            outs = {"metric": metric, "utility": utility,
-                    "interval": interval, "cost": cost,
-                    "consumed_sum": jnp.sum(consumed),
-                    "resid": resid, "alpha": alpha, "stale": stale,
-                    "bcounts": bstate_e["counts"],
-                    "butil": bstate_e["utility_sum"],
-                    "nxt_i": nxt_i, "nxt_c": nxt_c, "fin": fin,
-                    "new_global": new_global}
-            return ((new_global, fleet, consumed, fetch_ver, version,
-                     metric), outs)
+            return event_step(
+                state, knobs, e_sorted[j], f_sorted[j], interval_l[j],
+                cost_l[j], jax.tree.map(lambda a: a[j], p_new_stack),
+                k_sels[j], k_costs[j])
 
         def lane_skip(state):
             outs = {"metric": jnp.float32(0), "utility": jnp.float32(0),
@@ -504,119 +556,7 @@ def make_async_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
                     arm_utility=stk["butil"])
         return new_carry
 
-    def body_one_scn(carry: Dict[str, Any], knobs: Dict[str, jax.Array]):
-        # the scenario variant of body_one: the popped edge's activity
-        # bit decides between a real completion and a dropout PROBE —
-        # a probe discards the block (no merge, no charge, no bandit
-        # pull, no version bump) and retries the same in-flight block
-        # after a reconnect delay, so churned edges burn wall clock but
-        # never budget, and the merge chain skips them entirely.
-        ucb_c, budget = knobs["ucb_c"], knobs["budget"]
-        costs_ek = knobs["costs_ek"]                            # [E, K]
-        alpha0 = knobs["async_alpha"]
-        scn_active, scn_mult = knobs["scn_active"], knobs["scn_mult"]
-        gparams, edge_params = carry["gparams"], carry["edge_params"]
-        fleet, consumed = carry["fleet"], carry["consumed"]
-        finish = carry["finish"]
-        infl_i, infl_c = carry["infl_i"], carry["infl_c"]
-        fetch_ver, version = carry["fetch_ver"], carry["version"]
-        t, prev_metric = carry["t"], carry["prev_metric"]
-        hist = carry["hist"]
-
-        rng, k_sel, k_data, k_cost = split_event_keys(carry["rng"])
-        e = jnp.argmin(finish)
-        wall = finish[e]
-        slot_i = jnp.mod(t, period)
-        act_row = scn_active[slot_i] > 0                        # [E]
-        is_act = act_row[e]
-        interval, cost = infl_i[e], infl_c[e]
-        p_e = gather_edge_stack(jax.tree.map(lambda a: a[e],
-                                             edge_params))
-        # a dropped edge runs zero masked work (interval 0) and the
-        # drift shift rotates the sampling window
-        shift = knobs["scn_drift"] * t.astype(jnp.float32)
-        p_new = local_block(p_e, e, jnp.where(is_act, interval, 0),
-                            jax.random.fold_in(k_data, e), shift)
-        # charge-at-completion, live edges only: probes are free
-        consumed = consumed.at[e].add(jnp.where(is_act, cost, 0.0))
-        alpha = staleness_alpha(alpha0, version, fetch_ver[e], n_edges)
-        if spec is not None:
-            stale = ((version - fetch_ver[e]).astype(jnp.float32)
-                     / jnp.float32(max(n_edges, 1)))
-        merged = staleness_merge(gparams, p_new, alpha)
-        new_global = jax.tree.map(
-            lambda m, g: jnp.where(is_act, m, g), merged, gparams)
-        version = version + jnp.where(is_act, 1, 0)
-        metric, utility = eval_step(new_global, gparams, prev_metric)
-        # arm -1 makes the bandit update a no-op (its valid guard), so
-        # a probe pulls nothing
-        bstate_e = jax_bandit_update(
-            bandit_slice(fleet, e),
-            jnp.where(is_act, interval - 1, -1), utility, cost)
-        fleet = bandit_place(fleet, e, bstate_e)
-        # only a live edge refetches the global model
-        edge_params = constrain_edge_stack(jax.tree.map(
-            lambda a, g: a.at[e].set(jnp.where(is_act, g, a[e])),
-            edge_params, new_global))
-        fetch_ver = fetch_ver.at[e].set(
-            jnp.where(is_act, version, fetch_ver[e]))
-        resid = budget - consumed[e]
-        # straggler spikes scale the NEXT block's cost surface at
-        # scheduling time (cost = m * (i*comp + comm) by linearity)
-        m = scn_mult[slot_i, e]
-        _, nxt_i, nxt_c, fin = schedule_block(
-            bstate_e, resid, costs_ek[e] * m, ucb_c,
-            knobs["min_edge_cost"][e] * m, knobs["cost_noise"],
-            knobs["comp"][e] * m, knobs["comm"][e] * m, wall,
-            jax.random.fold_in(k_sel, e),
-            jax.random.fold_in(k_cost, e))
-        # a probe keeps its in-flight block and retries after a
-        # reconnect delay of the edge's minimum block cost
-        fin = jnp.where(is_act, fin,
-                        wall + knobs["min_edge_cost"][e])
-        nxt_i = jnp.where(is_act, nxt_i, interval)
-        nxt_c = jnp.where(is_act, nxt_c, cost)
-        finish = finish.at[e].set(fin)
-        infl_i = infl_i.at[e].set(nxt_i)
-        infl_c = infl_c.at[e].set(nxt_c)
-        n_act_fleet = jnp.sum(act_row.astype(jnp.int32))
-        hist = {
-            "metric": hist["metric"].at[t].set(metric),
-            "utility": hist["utility"].at[t].set(
-                jnp.where(is_act, utility, 0.0)),
-            "interval": hist["interval"].at[t].set(
-                jnp.where(is_act, interval, 0)),
-            "edge": hist["edge"].at[t].set(e.astype(jnp.int32)),
-            "cost": hist["cost"].at[t].set(
-                jnp.where(is_act, cost, 0.0)),
-            "consumed": hist["consumed"].at[t].set(jnp.sum(consumed)),
-            "wall": hist["wall"].at[t].set(wall),
-            "active_edges": hist["active_edges"].at[t].set(n_act_fleet),
-        }
-        new_carry = {"gparams": new_global, "edge_params": edge_params,
-                     "fleet": fleet, "consumed": consumed,
-                     "finish": finish, "infl_i": infl_i,
-                     "infl_c": infl_c, "fetch_ver": fetch_ver,
-                     "version": version, "t": t + 1, "rng": rng,
-                     "prev_metric": metric, "wall": wall, "hist": hist}
-        if spec is not None:
-            with jax.named_scope("obs.telemetry"):
-                new_carry["telem"] = async_ring_record(
-                    carry["telem"], spec, t=t, edge=e,
-                    arm=interval - 1,
-                    cost=jnp.where(is_act, cost, 0.0),
-                    budget_resid=resid, alpha=alpha, staleness=stale,
-                    interarrival=wall - carry["wall"],
-                    bstate_e=bstate_e,
-                    scn=(n_act_fleet,
-                         1 - is_act.astype(jnp.int32),
-                         jnp.int32(0)))
-        return new_carry
-
-    if scn is not None:
-        body = body_one_scn
-    else:
-        body = body_one if batch_k == 1 else body_wave
+    body = body_one if batch_k == 1 else body_wave
 
     def finalize(carry: Dict[str, Any], knobs: Dict[str, jax.Array]):
         out = dict(carry["hist"])

@@ -76,9 +76,10 @@ import numpy as np
 from jax import lax
 
 from repro.config import OL4ELConfig
-from repro.core.bandit import (jax_bandit_init, jax_bandit_update,
-                               jax_selection_weights)
+from repro.core.bandit import jax_bandit_init, jax_bandit_update
 from repro.core.coordinator import edge_speed_factors
+from repro.el.scenarios.baselines import (INGRAPH_POLICY_ORDER, ol4el_arm,
+                                          select_arm_switch)
 
 Params = Any
 
@@ -109,7 +110,6 @@ def support_matrix() -> str:
     """The scenario/cost-model support matrix, rendered for error
     messages — so an unsupported combination is rejected at the front
     door with the full menu, instead of failing late inside tracing."""
-    from repro.el.scenarios.baselines import INGRAPH_POLICY_ORDER
     return (
         "supported in-graph matrix:\n"
         "  mode        'sync' (repro.el.ingraph) | 'async' "
@@ -342,11 +342,11 @@ def make_local_block(model, xs: jax.Array, ys: jax.Array,
                     key: jax.Array, shift: jax.Array = None) -> Params:
         def body(p, step):
             u = jax.random.uniform(jax.random.fold_in(key, step), (batch,))
-            idx = (u * n_per_edge[edge].astype(jnp.float32)).astype(jnp.int32)
+            n = n_per_edge[edge]
+            n_f = n.astype(jnp.float32)
+            idx = (u * n_f).astype(jnp.int32)
             if drift:
-                off = (shift * n_per_edge[edge].astype(jnp.float32)
-                       ).astype(jnp.int32)
-                idx = jnp.mod(idx + off, n_per_edge[edge])
+                idx = jnp.mod(idx + (shift * n_f).astype(jnp.int32), n)
             b = {"x": xs[edge][idx], "y": ys[edge][idx]}
             p2, _ = model.local_step(p, b, lr)
             take = step < interval
@@ -589,14 +589,20 @@ def make_sync_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
     straggler cost, residual budget and the bandit's per-arm statistics
     at ``t % ring_size`` (under ``jax.named_scope("obs.telemetry")``),
     surfaced by ``finalize`` as ``out["telemetry"]``.
+
+    ``cfg.scenario`` is static too: with a ``ScenarioSpec`` the same
+    ``cond``/``body`` pace on the round's active edges, select through
+    the traced policy switch, mask and drift the edge round, renormalize
+    the aggregation over live edges and charge only them; without one
+    none of those ops is traced.
     """
     from repro.obs.rings import (as_spec, finalize_telemetry,
                                  sync_ring_init, sync_ring_record)
     spec = as_spec(telemetry)
     check_ingraph_support(cfg, caller="make_sync_program")
-    # fleet-dynamics scenario: None keeps every closure below EXACTLY
-    # today's traced code (the scenario branch is statically absent);
-    # a ScenarioSpec swaps in the mask-aware cond/body variants.
+    # fleet-dynamics scenario: each of its differences is a static
+    # branch of the one cond/body below, taken only with a
+    # ScenarioSpec, so None traces no scenario op at all
     scn = cfg.scenario
     period = scn.period if scn is not None else 0
 
@@ -632,28 +638,42 @@ def make_sync_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
         metric_fn = jax.shard_map(metric_fn, mesh=mesh, in_specs=P(),
                                   out_specs=P(), check_vma=False)
 
-    def weighted_mean(trees: Params) -> Params:
+    def weighted_mean(trees: Params, w: jax.Array) -> Params:
         return jax.tree.map(
             lambda leaf: jnp.einsum(
-                "e...,e->...", leaf.astype(jnp.float32), w_agg
+                "e...,e->...", leaf.astype(jnp.float32), w
             ).astype(leaf.dtype), trees)
 
     def classic_round(params: Params, interval: jax.Array,
-                      keys: jax.Array) -> Params:
+                      keys: jax.Array, act: Optional[jax.Array] = None,
+                      shift: Optional[jax.Array] = None) -> Params:
         edge_ids = jnp.arange(n_edges)
         bcast = jax.tree.map(
             lambda x: jnp.broadcast_to(x, (n_edges,) + x.shape), params)
         # data plane: the per-edge param stack (and with it the
         # vmapped local blocks) shards over the mesh's edge axes ...
         bcast = constrain_edge_stack(bcast)
-        edge_params = jax.vmap(local_block, in_axes=(0, 0, None, 0))(
-            bcast, edge_ids, interval, keys)
+        if act is None:
+            edge_params = jax.vmap(local_block, in_axes=(0, 0, None, 0))(
+                bcast, edge_ids, interval, keys)
+        else:
+            # a dropped edge runs ZERO masked work: interval 0 masks
+            # every scan step
+            edge_params = jax.vmap(local_block,
+                                   in_axes=(0, 0, 0, 0, None))(
+                bcast, edge_ids, jnp.where(act, interval, 0), keys, shift)
         # ... and is all-gathered BEFORE the aggregation so the
         # einsum reduces replicated, in the unsharded program's
         # exact accumulation order (bit-identity; a psum over the
         # sharded edge dim would be an ulp off)
         edge_params = gather_edge_stack(edge_params)
-        return weighted_mean(edge_params)
+        if act is None:
+            return weighted_mean(edge_params, w_agg)
+        # mask-aware aggregation: dead edges carry zero weight and the
+        # live weights renormalize
+        w_act = w_agg * act.astype(jnp.float32)
+        return weighted_mean(edge_params,
+                             w_act / jnp.maximum(jnp.sum(w_act), 1e-12))
 
     edge_round = token_round if tokens else classic_round
 
@@ -685,9 +705,15 @@ def make_sync_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
 
     def cond(carry: Dict[str, Any], knobs: Dict[str, jax.Array]):
         resid = knobs["budget"] - carry["consumed"]                  # [E]
-        affordable = (jnp.min(resid)
-                      >= jnp.min(knobs["costs_k"]) - 1e-12)
-        exhausted = jnp.any(resid < knobs["min_edge_cost"])
+        live = resid
+        if scn is not None:
+            # feasibility paces on the tightest ACTIVE edge this round —
+            # dropped edges neither spend nor constrain the fleet
+            act = knobs["scn_active"][jnp.mod(carry["t"], period)] > 0
+            live = jnp.where(act, resid, jnp.inf)
+        affordable = jnp.min(live) >= jnp.min(knobs["costs_k"]) - 1e-12
+        low = resid < knobs["min_edge_cost"]
+        exhausted = jnp.any(low if scn is None else act & low)
         return (carry["t"] < max_rounds) & affordable & ~exhausted
 
     def body(carry: Dict[str, Any], knobs: Dict[str, jax.Array]):
@@ -700,18 +726,32 @@ def make_sync_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
         consumed, t = carry["consumed"], carry["t"]
         prev_metric, wall = carry["prev_metric"], carry["wall"]
         hist = carry["hist"]
+        # the round's activity row [E]; None without a scenario
+        act = None
+        if scn is not None:
+            slot_i = jnp.mod(t, period)
+            act = knobs["scn_active"][slot_i] > 0
 
         rng, k_sel, k_data = jax.random.split(carry["rng"], 3)
-        resid = jnp.min(budget - consumed)
-        w = jax_selection_weights(bstate, resid, costs_k, ucb_c)
-        logits = jnp.where(w > 0, jnp.log(jnp.maximum(w, 1e-30)),
-                           -jnp.inf)
-        arm = jax.random.categorical(k_sel, logits)
+        if act is None:
+            arm = ol4el_arm(bstate, jnp.min(budget - consumed), costs_k,
+                            ucb_c, k_sel)
+        else:
+            # traced policy switch: OL4EL bandit vs the task-allocation
+            # baselines, selected by the policy_id knob (sweepable axis)
+            resid = jnp.min(jnp.where(act, budget - consumed, jnp.inf))
+            arm = select_arm_switch(knobs["policy_id"], bstate, resid,
+                                    costs_k, ucb_c, k_sel)
         interval = arm + 1
 
         edge_ids = jnp.arange(n_edges)
         keys = jax.vmap(lambda e: jax.random.fold_in(k_data, e))(edge_ids)
-        new_params = edge_round(params, interval, keys)
+        if act is None:
+            new_params = edge_round(params, interval, keys)
+        else:
+            # the drift shift rotates every edge's sampling window
+            shift = knobs["scn_drift"] * t.astype(jnp.float32)
+            new_params = classic_round(params, interval, keys, act, shift)
 
         # straggler semantics: every edge's clock advances by the
         # slowest edge's round time (matches CloudCoordinator.charge
@@ -729,8 +769,17 @@ def make_sync_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
         eps = jax.random.normal(k_cost, (n_edges,))
         mult = jnp.maximum(0.1, 1.0 + cost_noise * eps)
         round_costs = round_costs * mult
-        slot = jnp.max(round_costs)
-        consumed = consumed + slot
+        if act is None:
+            slot = jnp.max(round_costs)
+            consumed = consumed + slot
+        else:
+            # scenario straggler spikes compose with the i.i.d. noise
+            # model; the slot paces on the slowest ACTIVE edge, and
+            # only active edges are charged — a dropped edge's budget
+            # is untouched
+            round_costs = round_costs * knobs["scn_mult"][slot_i]
+            slot = jnp.max(jnp.where(act, round_costs, 0.0))
+            consumed = consumed + jnp.where(act, slot, 0.0)
 
         if metric_fn is not None:
             metric = metric_fn(new_params)
@@ -750,119 +799,29 @@ def make_sync_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
             "consumed": hist["consumed"].at[t].set(jnp.sum(consumed)),
             "wall": hist["wall"].at[t].set(wall),
         }
+        if act is not None:
+            n_active = jnp.sum(act.astype(jnp.int32))
+            hist["active_edges"] = carry["hist"]["active_edges"].at[t].set(
+                n_active)
         new_carry = {"params": new_params, "bstate": bstate,
                      "consumed": consumed, "t": t + 1, "rng": rng,
                      "prev_metric": metric, "wall": wall, "hist": hist}
         if spec is not None:
+            scn_counts = None
+            if act is not None:
+                # dropout/rejoin deltas vs the previous round's mask
+                # (round 0 measures against the nominal full fleet)
+                prev = jnp.where(
+                    t > 0, knobs["scn_active"][jnp.mod(t - 1, period)],
+                    jnp.ones((n_edges,), jnp.float32)) > 0
+                scn_counts = (n_active,
+                              jnp.sum((prev & ~act).astype(jnp.int32)),
+                              jnp.sum((~prev & act).astype(jnp.int32)))
             with jax.named_scope("obs.telemetry"):
                 new_carry["telem"] = sync_ring_record(
                     carry["telem"], spec, t=t, arm=arm, round_cost=slot,
                     budget_resid=jnp.min(budget - consumed),
-                    bstate=bstate)
-        return new_carry
-
-    def cond_scn(carry: Dict[str, Any], knobs: Dict[str, jax.Array]):
-        # feasibility paces on the tightest ACTIVE edge this round —
-        # dropped edges neither spend nor constrain the fleet
-        resid = knobs["budget"] - carry["consumed"]                  # [E]
-        act = knobs["scn_active"][jnp.mod(carry["t"], period)] > 0
-        affordable = (jnp.min(jnp.where(act, resid, jnp.inf))
-                      >= jnp.min(knobs["costs_k"]) - 1e-12)
-        exhausted = jnp.any(act & (resid < knobs["min_edge_cost"]))
-        return (carry["t"] < max_rounds) & affordable & ~exhausted
-
-    def body_scn(carry: Dict[str, Any], knobs: Dict[str, jax.Array]):
-        from repro.el.scenarios.baselines import select_arm_switch
-        ucb_c = knobs["ucb_c"]
-        budget = knobs["budget"]
-        comp, comm = knobs["comp"], knobs["comm"]
-        costs_k = knobs["costs_k"]
-        cost_noise = knobs["cost_noise"]
-        scn_active, scn_mult = knobs["scn_active"], knobs["scn_mult"]
-        params, bstate = carry["params"], carry["bstate"]
-        consumed, t = carry["consumed"], carry["t"]
-        prev_metric, wall = carry["prev_metric"], carry["wall"]
-        hist = carry["hist"]
-
-        slot_i = jnp.mod(t, period)
-        act = scn_active[slot_i] > 0                                 # [E]
-
-        rng, k_sel, k_data = jax.random.split(carry["rng"], 3)
-        resid = jnp.min(jnp.where(act, budget - consumed, jnp.inf))
-        # traced policy switch: OL4EL bandit vs the task-allocation
-        # baselines, selected by the policy_id knob (sweepable axis)
-        arm = select_arm_switch(knobs["policy_id"], bstate, resid,
-                                costs_k, ucb_c, k_sel)
-        interval = arm + 1
-
-        edge_ids = jnp.arange(n_edges)
-        keys = jax.vmap(lambda e: jax.random.fold_in(k_data, e))(edge_ids)
-        bcast = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (n_edges,) + x.shape), params)
-        bcast = constrain_edge_stack(bcast)
-        # a dropped edge runs ZERO masked work: interval 0 masks every
-        # scan step; the drift shift rotates its sampling window
-        edge_iv = jnp.where(act, interval, 0)
-        shift = knobs["scn_drift"] * t.astype(jnp.float32)
-        edge_params = jax.vmap(local_block, in_axes=(0, 0, 0, 0, None))(
-            bcast, edge_ids, edge_iv, keys, shift)
-        edge_params = gather_edge_stack(edge_params)
-        # mask-aware aggregation: dead edges carry zero weight and the
-        # live weights renormalize (the merge chain skips them)
-        w_act = w_agg * act.astype(jnp.float32)
-        w_act = w_act / jnp.maximum(jnp.sum(w_act), 1e-12)
-        new_params = jax.tree.map(
-            lambda leaf: jnp.einsum(
-                "e...,e->...", leaf.astype(jnp.float32), w_act
-            ).astype(leaf.dtype), edge_params)
-
-        round_costs = interval.astype(jnp.float32) * comp + comm  # [E]
-        k_cost = jax.random.fold_in(k_data, n_edges)
-        eps = jax.random.normal(k_cost, (n_edges,))
-        mult = jnp.maximum(0.1, 1.0 + cost_noise * eps)
-        # scenario straggler spikes compose with the i.i.d. noise model
-        round_costs = round_costs * mult * scn_mult[slot_i]
-        # the slot paces on the slowest ACTIVE edge, and only active
-        # edges are charged — a dropped edge's budget is untouched
-        slot = jnp.max(jnp.where(act, round_costs, 0.0))
-        consumed = consumed + jnp.where(act, slot, 0.0)
-
-        if metric_fn is not None:
-            metric = metric_fn(new_params)
-        else:
-            metric = jnp.float32(jnp.nan)
-        if cfg.utility == "eval_gain":
-            utility = metric - prev_metric
-        else:                              # param_delta (§III.A)
-            utility = 1.0 / (1.0 + _tree_l2(params, new_params))
-
-        bstate = jax_bandit_update(bstate, arm, utility, slot)
-        wall = wall + slot
-        n_active = jnp.sum(act.astype(jnp.int32))
-        hist = {
-            "metric": hist["metric"].at[t].set(metric),
-            "utility": hist["utility"].at[t].set(utility),
-            "interval": hist["interval"].at[t].set(interval),
-            "consumed": hist["consumed"].at[t].set(jnp.sum(consumed)),
-            "wall": hist["wall"].at[t].set(wall),
-            "active_edges": hist["active_edges"].at[t].set(n_active),
-        }
-        new_carry = {"params": new_params, "bstate": bstate,
-                     "consumed": consumed, "t": t + 1, "rng": rng,
-                     "prev_metric": metric, "wall": wall, "hist": hist}
-        if spec is not None:
-            # dropout/rejoin deltas vs the previous round's mask (round
-            # 0 measures against the nominal full fleet)
-            prev = jnp.where(t > 0,
-                             scn_active[jnp.mod(t - 1, period)],
-                             jnp.ones((n_edges,), jnp.float32)) > 0
-            dropouts = jnp.sum((prev & ~act).astype(jnp.int32))
-            rejoins = jnp.sum((~prev & act).astype(jnp.int32))
-            with jax.named_scope("obs.telemetry"):
-                new_carry["telem"] = sync_ring_record(
-                    carry["telem"], spec, t=t, arm=arm, round_cost=slot,
-                    budget_resid=jnp.min(budget - consumed),
-                    bstate=bstate, scn=(n_active, dropouts, rejoins))
+                    bstate=bstate, scn=scn_counts)
         return new_carry
 
     def finalize(carry: Dict[str, Any], knobs: Dict[str, jax.Array]):
@@ -883,8 +842,6 @@ def make_sync_cell(model, edge_data, eval_set, cfg: OL4ELConfig, *,
                                                   carry["t"], spec)
         return carry["params"], out
 
-    if scn is not None:
-        cond, body = cond_scn, body_scn
     return ELCell(init=init, cond=cond, body=body, finalize=finalize,
                   horizon=max_rounds)
 

@@ -1,14 +1,14 @@
 """In-graph selection-policy switch: OL4EL vs task-allocation baselines.
 
-The scenario-path sync program routes arm selection through one
+With a scenario, the sync program routes arm selection through one
 ``lax.switch`` over these branches, selected by the traced ``policy_id``
 knob — so "OL4EL vs baselines under churn" is ONE vmapped program with
 ``policy`` as an ordinary sweep axis, every cell sharing the executable.
 
-Branch 0 is the OL4EL budget-limited UCB bandit, written with exactly
-the ops the scenario-less program uses (``jax_selection_weights`` →
-log-weights → ``categorical``).  Branches 1–2 are the PAPERS.md
-task-allocation baselines:
+Branch 0 is the OL4EL budget-limited UCB bandit, ``ol4el_arm``
+(``jax_selection_weights`` → log-weights → ``categorical``), the same
+function the scenario-less sync round calls directly.  Branches 1–2 are
+the PAPERS.md task-allocation baselines:
 
 - ``task_alloc`` — modeled on "Adaptive task allocation for mobile edge
   learning" (arXiv 1811.03748): allocate the largest locally-feasible
@@ -54,8 +54,9 @@ def ingraph_policy_id(name: str) -> int:
     return INGRAPH_POLICY_ORDER.index(name)
 
 
-def _ol4el_arm(bstate, resid, costs, ucb_c, key):
-    # the exact selection ops of the scenario-less sync program
+def ol4el_arm(bstate, resid, costs, ucb_c, key):
+    """The OL4EL budget-limited UCB draw: the compiled sync round's
+    selection, with or without a scenario."""
     w = jax_selection_weights(bstate, resid, costs, ucb_c)
     logits = jnp.where(w > 0, jnp.log(jnp.maximum(w, 1e-30)), -jnp.inf)
     return jax.random.categorical(key, logits).astype(jnp.int32)
@@ -79,7 +80,7 @@ def _delay_energy_arm(bstate, resid, costs, ucb_c, key):
     return jnp.argmin(score).astype(jnp.int32)
 
 
-_BRANCHES = (_ol4el_arm, _task_alloc_arm, _delay_energy_arm)
+_BRANCHES = (ol4el_arm, _task_alloc_arm, _delay_energy_arm)
 
 
 def select_arm_switch(policy_id, bstate, resid, costs, ucb_c, key):

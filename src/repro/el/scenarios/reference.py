@@ -83,14 +83,15 @@ def replay_sync_scenario(cfg: OL4ELConfig,
     while t < max_rounds:
         act = sched_act[t % period] > 0                          # [E]
         resid = budget - consumed
-        # cond_scn verbatim: pace on the tightest ACTIVE edge
+        # the sync cond's scenario branch: pace on the tightest
+        # ACTIVE edge
         affordable = (np.min(np.where(act, resid, np.inf))
                       >= np.min(costs_k) - 1e-12)
         exhausted = bool(np.any(act & (resid < min_edge_cost)))
         if not (affordable and not exhausted):
             break
         interval = np.int32(intervals[t])
-        # body_scn bookkeeping verbatim (float32 elementwise, so the
+        # the sync body's scenario charge (float32 elementwise, so the
         # replay is bit-exact against the compiled history)
         round_costs = (np.float32(interval) * comp + comm).astype(
             np.float32)

@@ -19,7 +19,8 @@ import pytest
 
 from repro.config import OL4ELConfig
 from repro.el import ELSession, FleetServer, SweepSpec, TenantRun
-from repro.el.events import ASYNC_KNOB_NAMES, async_knob_names
+from repro.el.events import (ASYNC_KNOB_NAMES, async_knob_names,
+                             async_knobs, make_async_program)
 from repro.el.ingraph import (KNOB_NAMES, check_ingraph_support,
                               make_sync_program, support_matrix,
                               sync_knob_names, sync_knobs)
@@ -357,6 +358,43 @@ def test_identity_scenario_runs_all_edges_active(svm):
     assert n > 0
     np.testing.assert_array_equal(out["active_edges"][:n],
                                   np.full(n, 4, np.int32))
+
+
+def _run_program(fx, cfg):
+    """Final params and raw out dict of one compiled run: sync over 48
+    rounds (n_samples-weighted), async as single events over 64."""
+    ex = fx["executor"]
+    args = (ex.model, ex.edge_data, ex.eval_set, cfg)
+    if cfg.mode == "sync":
+        prog = make_sync_program(
+            *args, lr=ex.lr, batch=ex.batch, max_rounds=48,
+            n_samples=np.asarray(fx["n_samples"], np.float64))
+        knobs = sync_knobs(cfg)
+    else:
+        prog = make_async_program(*args, lr=ex.lr, batch=ex.batch,
+                                  max_events=64, batch_k=1)
+        knobs = async_knobs(cfg)
+    return jax.tree.map(np.asarray, jax.jit(prog)(
+        fx["init_params"], jax.random.key(cfg.seed + 17), knobs))
+
+
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_identity_scenario_equals_no_scenario_bit_for_bit(svm, mode):
+    """Under ScenarioSpec() every edge is active, nothing drifts and
+    every multiplier is 1, so each scenario branch of the round and
+    event bodies must be an exact identity: the final params and every
+    out key both runs share equal the scenario=None run's bit for bit
+    (NaN equal to NaN)."""
+    cfg = _cfg(svm, mode)
+    params, out = _run_program(svm, cfg)
+    s_params, s_out = _run_program(
+        svm, dataclasses.replace(cfg, scenario=ScenarioSpec()))
+    assert int(out["n_rounds"]) > 0
+    assert set(out) < set(s_out)        # the scenario only adds keys
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(s_params)):
+        np.testing.assert_array_equal(a, b)
+    for key in out:
+        np.testing.assert_array_equal(out[key], s_out[key], err_msg=key)
 
 
 def test_churn_reference_replay_matches_event_for_event(svm):
